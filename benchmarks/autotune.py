@@ -127,6 +127,8 @@ print(f"ROW,kind=resolve,sparse={{int(res_sparse is not None)}},"
 def _spawn(code: str, devices: int, timeout: int = 1500):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # a CPU gate: never reach for a chip the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _SRC + os.pathsep * bool(env.get("PYTHONPATH")) \
         + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", code], env=env,

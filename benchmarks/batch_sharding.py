@@ -92,6 +92,8 @@ def run(sizes=SIZES, devices: int = DEVICES, repeats: int = 7,
     """Measure in a forced-multi-device subprocess; returns CSV rows."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # a CPU gate: never reach for a chip the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _SRC + os.pathsep * bool(env.get("PYTHONPATH")) \
         + env.get("PYTHONPATH", "")
     code = _WORKER.format(sizes=tuple(sizes), repeats=repeats,

@@ -89,6 +89,8 @@ print(f"ROW,kind=throughput,n={{n}},devices={{devices}},waves={{ts // devices}},
 def _env(devices: int):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # a CPU gate: never reach for a chip the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _SRC + os.pathsep * bool(env.get("PYTHONPATH")) \
         + env.get("PYTHONPATH", "")
     return env

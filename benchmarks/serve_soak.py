@@ -178,6 +178,8 @@ def _run_once(cache_dir: str, *, devices: int, requests: int,
               rate_hz: float, seed: int, table: str) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # a CPU gate: never reach for a chip the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _SRC + os.pathsep * bool(env.get("PYTHONPATH")) \
         + env.get("PYTHONPATH", "")
     code = _WORKER.format(n=N, devices=devices, max_batch=MAX_BATCH,

@@ -11,8 +11,6 @@ never an exception from ``submit``), step/drain the loop, read one
 metrics snapshot.
 """
 
-import tempfile
-
 import jax
 
 jax.config.update("jax_enable_x64", True)
@@ -21,10 +19,12 @@ import numpy as np  # noqa: E402
 
 from repro.core.solver import SolverConfig  # noqa: E402
 from repro.serve import (LaneSpec, PermanentService, ServiceConfig,  # noqa: E402
-                         ShedError, start_metrics_server)
+                         ShedError, enable_compile_cache,
+                         start_metrics_server)
 
 rng = np.random.default_rng(0)
-cache_dir = tempfile.mkdtemp(prefix="xla-cache-")
+# JAX_COMPILATION_CACHE_DIR when set, else the checkout's .jax_cache/
+cache_dir = enable_compile_cache()
 
 # --- 1. configure: lanes, budgets, warm-up ---------------------------------
 # Two strict-priority lanes; each lane's slo_s doubles as the default

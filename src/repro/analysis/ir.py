@@ -121,6 +121,13 @@ def _build(entry: Entry, precision: str, B: int):
 
     if entry.route == "dense" and entry.engine == "jnp":
         from ..core import ryser
+        if entry.dtype == "c128":
+            # complex runs as split planes, the scalar entry as the B=1
+            # batch program (perm_ryser_chunked -> _complex_batched)
+            Bc = 1 if entry.arity == "scalar" else B
+            fn = lambda Ar, Ai: ryser.batched_values_complex(
+                Ar, Ai, T, C, precision)
+            return fn, (_sds((Bc, n, n), f64), _sds((Bc, n, n), f64))
         if entry.arity == "scalar":
             fn = lambda A: ryser.perm_ryser_chunked(
                 A, num_chunks=NUM_CHUNKS, precision=precision)
@@ -176,18 +183,14 @@ def _build(entry: Entry, precision: str, B: int):
 
     # campaign wave bodies: the per-device program run under shard_map
     # by slice_sums_on_mesh/permanent_on_mesh, with a *traced* chunk
-    # base -- one program for every device.
+    # base -- one program for every device.  A complex matrix travels
+    # as its (re, im) real planes.
     from ..core import distributed
-    if entry.engine == "jnp":
-        fn = lambda A, fc: distributed._dyn_chunk_partials(
-            A, fc, CPS, CHUNK, precision)
-    elif entry.dtype == "f64":
-        fn = lambda A, fc: distributed._pallas_device_partials(
-            A, fc, CPS, CHUNK, precision)
-    else:
-        fn = lambda A, fc: distributed._pallas_device_partials_complex(
-            A, fc, CPS, CHUNK, precision)
-    return fn, (_sds((n, n), dt), _sds((), i32))
+    planes = 1 if entry.dtype == "f64" else 2
+    body = distributed._dyn_chunk_partials if entry.engine == "jnp" \
+        else distributed._pallas_device_partials
+    fn = lambda planes, fc: body(planes, fc, CPS, CHUNK, precision)
+    return fn, ((_sds((n, n), f64),) * planes, _sds((), i32))
 
 
 def trace_entry(entry: Entry, precision: str, B: int = CANON_B):
@@ -232,8 +235,8 @@ def _aval_str(aval) -> str:
 
 
 def _is_jaxpr(v) -> bool:
-    import jax
-    return isinstance(v, (jax.core.Jaxpr, jax.core.ClosedJaxpr))
+    from jax.extend import core
+    return isinstance(v, (core.Jaxpr, core.ClosedJaxpr))
 
 
 def _sanitize(v, subs: list) -> str:
@@ -309,9 +312,8 @@ def _reduced_extents(eqn) -> tuple[int, ...]:
 
 
 def _render_jaxpr(jaxpr, consts, walk: _Walk, depth: int):
-    import jax
     import numpy as np
-    Literal = jax.core.Literal
+    from jax.extend.core import ClosedJaxpr, Literal
     pad = "  " * depth
     names: dict = {}
 
@@ -363,7 +365,7 @@ def _render_jaxpr(jaxpr, consts, walk: _Walk, depth: int):
                     dst=_short_dtype(eqn.outvars[0].aval.dtype)))
 
         for sub in subs:
-            if isinstance(sub, jax.core.ClosedJaxpr):
+            if isinstance(sub, ClosedJaxpr):
                 _render_jaxpr(sub.jaxpr, sub.consts, walk, depth + 1)
             else:
                 _render_jaxpr(sub, [None] * len(sub.constvars), walk,
@@ -500,10 +502,9 @@ def _mesh_programs(log=None):
     mesh = Mesh(np.asarray(devs[:8]), ("d",))
     T, C, _ = chunk_geometry(N, NUM_CHUNKS)
     f64, i32 = np.float64, np.int32
-    A = _sds((N, N), f64)
-    Ac = _sds((N, N), np.complex128)
+    A = (_sds((N, N), f64),)
+    Ac = (_sds((N, N), f64),) * 2      # a complex matrix's real planes
     sl = _sds((D, 1), i32)
-    stack = _sds((D, N, N), f64)
 
     progs = []
 
@@ -531,10 +532,11 @@ def _mesh_programs(log=None):
           (Ac, sl, _sds((D, 1), f64)), ONE_PSUM)
     lower("mesh.dense_batch",
           distributed._dense_batch_mesh_fn(mesh, T, C, "dq_acc"),
-          (stack,), NONE)
+          (_sds((D, N, N), f64),), NONE)
     lower("mesh.sparse_batch",
           distributed._sparse_batch_mesh_fn(mesh, T, C, "dq_acc"),
-          (stack, _sds((D, N, MAXDEG), i32), _sds((D, N, MAXDEG), f64)),
+          (_sds((D, N, N), f64), _sds((D, N, MAXDEG), i32),
+           _sds((D, N, MAXDEG), f64)),
           NONE)
     return progs
 

@@ -53,7 +53,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from functools import lru_cache
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -64,14 +64,37 @@ from ..utils.compat import shard_map
 from . import gray as G
 from . import precision as P
 from .resume import JobState
-from .ryser import (batched_values, batched_values_complex, chunk_geometry,
-                    complex_precision, nw_base_vector, _final_factor)
+from .ryser import (batched_values, batched_values_complex, chain_prod_complex,
+                    chunk_geometry, complex_precision, nw_base_vector,
+                    _final_factor)
 from .stepspace import DEFAULT_GEOMETRY, Geometry, plan_slices
 
 __all__ = ["permanent_on_mesh", "slice_sums_on_mesh", "run_campaign",
            "CampaignPaused",
            "batch_permanents_on_mesh", "sparse_batch_permanents_on_mesh",
            "DistributedPermanent", "plan_slices"]
+
+
+def _planes(A) -> tuple[np.ndarray, ...]:
+    """Host-side real planes of a matrix: ``(A,)`` or ``(re, im)``.
+
+    The step-space programs never see a complex dtype: the TPU compiler
+    has no c128 arithmetic (its x64 rewriter aborts the process on c128
+    converts, dots and products), so a complex matrix travels as two
+    real planes, like the batch engines' split-plane bodies.
+    """
+    A = np.asarray(A)
+    if np.iscomplexobj(A):
+        return (np.ascontiguousarray(A.real), np.ascontiguousarray(A.imag))
+    return (A.astype(np.float64),)
+
+
+def _join(parts) -> np.ndarray:
+    """Per-plane host arrays (last axis) back into real or complex."""
+    parts = np.asarray(parts)
+    if parts.shape[-1] == 1:
+        return parts[..., 0]
+    return parts[..., 0] + 1j * parts[..., 1]
 
 
 def _dyn_chunk_partials(A, first_chunk, T: int, C: int, precision: str):
@@ -83,20 +106,26 @@ def _dyn_chunk_partials(A, first_chunk, T: int, C: int, precision: str):
     shard_map, where every device runs the same program on different
     slice ids.  Needs jax_enable_x64 for n > 31 (the Pallas kernel uses a
     32-bit pair encoding on real TPUs instead; see kernels/ryser_pallas).
+
+    ``A`` is a real matrix (returns one TwoFloat) or the ``(re, im)``
+    plane pair of a complex one (returns one TwoFloat per plane; the
+    product is the explicit complex chain of ``ryser.chain_prod_complex``).
     """
-    n = A.shape[0]
+    planes = A if isinstance(A, tuple) else (A,)
+    n = planes[0].shape[0]
     k = int(math.log2(C))
     assert C == 1 << k and k >= 1
-    dtype = A.dtype
+    dtype = planes[0].dtype
     space = jnp.uint64(1) << jnp.uint64(n - 1)
+    dot = partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
 
-    x_base = nw_base_vector(A)
     starts = (first_chunk.astype(jnp.uint64)
               + jnp.arange(T, dtype=jnp.uint64)) * jnp.uint64(C)
     gray_s = starts ^ (starts >> jnp.uint64(1))
     jbits = jnp.arange(n, dtype=jnp.uint64)[:, None]
     Gbits = ((gray_s[None, :] >> jbits) & jnp.uint64(1)).astype(dtype)  # (n,T)
-    X0 = x_base[:, None] + A @ Gbits
+    X0 = tuple(nw_base_vector(M)[:, None] + dot(M, Gbits)
+               for M in planes)
 
     # schedules for w = 1..C-1 (host constants -- identical for all chunks)
     sched = G.changed_bit_schedule(k)
@@ -119,6 +148,13 @@ def _dyn_chunk_partials(A, first_chunk, T: int, C: int, precision: str):
     tail_live = g_tail <= (space - jnp.uint64(1))
     tail_j = jnp.where(tail_live, tail_j, 0)
 
+    def product(X):
+        if len(X) == 2:
+            return chain_prod_complex(*X)
+        # column product over the fixed axis n -- shape set by the matrix,
+        # never by device count, so association is stable across meshes
+        return (jnp.prod(X[0], axis=0),)  # permlint: disable=PL001  # fixed-axis column product
+
     def accum(acc, term):
         if precision == "dq_fast":
             t = P.tf_add_fast(P.TwoFloat(*acc), term)
@@ -135,44 +171,43 @@ def _dyn_chunk_partials(A, first_chunk, T: int, C: int, precision: str):
         col_j, bit, midf, par = inputs
         sign_bits = bit ^ (midf & lane_bitk)
         s = (2 * sign_bits - 1).astype(dtype)
-        X = X + A[:, col_j][:, None] * s[None, :]
-        # column product over the fixed axis n -- shape set by the matrix,
-        # never by device count, so association is stable across meshes
-        prod = jnp.prod(X, axis=0)  # permlint: disable=PL001  # fixed-axis column product
-        term = jnp.where(par == 1, -prod, prod)
-        return (X, accum(acc, term)), None
+        X = tuple(x + M[:, col_j][:, None] * s[None, :]
+                  for x, M in zip(X, planes))
+        acc = tuple(accum(a, jnp.where(par == 1, -p, p))
+                    for a, p in zip(acc, product(X)))
+        return (X, acc), None
 
     # derive the zero accumulator from X0 so its varying-manual-axes match
     # under shard_map (JAX >= 0.8 vma typing)
-    z = X0[0] * 0
+    z = X0[0][0] * 0
     (X, acc), _ = jax.lax.scan(
-        scan_body, (X0, (z, z)), (sched_j, base_bits, mid_flags, w_parity))
+        scan_body, (X0, tuple((z, z) for _ in planes)),
+        (sched_j, base_bits, mid_flags, w_parity))
 
     # tail: per-lane column via one-hot matmul (gather-free; kernel-identical)
     onehot = (tail_j[None, :] == jnp.arange(n, dtype=jnp.int32)[:, None])
-    X = X + (A @ onehot.astype(dtype)) \
-        * (tail_sign * tail_live.astype(dtype))[None, :]
-    prod = jnp.prod(X, axis=0)  # permlint: disable=PL001  # fixed-axis column product
+    scale = (tail_sign * tail_live.astype(dtype))[None, :]
+    X = tuple(x + dot(M, onehot.astype(dtype)) * scale
+              for x, M in zip(X, planes))
     neg = (C & 1) == 1
-    term = jnp.where(tail_live, -prod if neg else prod, jnp.zeros_like(prod))
-    acc = accum(acc, term)
-    if precision in ("kahan", "dd"):
-        return P.TwoFloat(acc[0], jnp.zeros_like(acc[0]))
-    return P.TwoFloat(acc[0], acc[1])
+    out = []
+    for a, prod in zip(acc, product(X)):
+        term = jnp.where(tail_live, -prod if neg else prod,
+                         jnp.zeros_like(prod))
+        a = accum(a, term)
+        lo = jnp.zeros_like(a[0]) if precision in ("kahan", "dd") else a[1]
+        out.append(P.TwoFloat(a[0], lo))
+    return tuple(out) if isinstance(A, tuple) else out[0]
 
 
-def _device_body(A_rep, slices_local, *, spd, chunks_per_slice, C, precision):
-    """Sum the slices owned by one device; returns scalar twofloat."""
-    acc = P.TwoFloat(jnp.zeros((), A_rep.dtype), jnp.zeros((), A_rep.dtype))
-    for i in range(spd):
-        first_chunk = slices_local[0, i] * chunks_per_slice
-        parts = _dyn_chunk_partials(A_rep, first_chunk, chunks_per_slice, C,
-                                    precision)
-        # parts has shape (chunks_per_slice,) fixed by CampaignSpec geometry,
-        # identical at every device count -- association is mesh-invariant
-        h, l = P.two_sum(jnp.sum(parts.hi), jnp.sum(parts.lo))  # permlint: disable=PL001  # shape-stable by CampaignSpec
-        acc = P.tf_add_tf(acc, P.TwoFloat(h, l))
-    return acc
+def _device_partials(planes, first_chunk, T: int, C: int, precision: str,
+                     backend: str, geometry: Geometry | None, vma):
+    """One device's chunk range through the chosen wave body; one
+    TwoFloat per plane."""
+    if backend == "pallas":
+        return _pallas_device_partials(planes, first_chunk, T, C, precision,
+                                       geometry=geometry, vma=vma)
+    return _dyn_chunk_partials(planes, first_chunk, T, C, precision)
 
 
 def permanent_on_mesh(A, mesh: Mesh, *, precision: str = "dq_acc",
@@ -189,18 +224,17 @@ def permanent_on_mesh(A, mesh: Mesh, *, precision: str = "dq_acc",
     device's chunk range instead of the jnp engine -- the full production
     path: two-level split -> Pallas grid -> lanes -> one psum.
 
-    Complex matrices work on both backends: the jnp chunk engine and the
-    twofloat psum reduction are add/sub-componentwise (TwoSum is exact
-    under complex addition), and the pallas backend launches the
-    split-plane complex kernel per device.  Unlike the batch engines, no
-    qq->kahan mapping is needed (or applied) here: the step-space family
-    has no twofloat product path -- ``_dyn_chunk_partials`` accumulates
-    qq as ``tf_add_acc`` for real and complex alike, so
-    ``permanent_on_mesh``, ``slice_sums_on_mesh`` and
+    Complex matrices work on both backends as split (re, im) planes: the
+    twofloat psum reduction runs per plane (TwoSum is componentwise), and
+    the pallas backend launches the split-plane complex kernel per device.
+    Unlike the batch engines, no qq->kahan mapping is needed (or applied)
+    here: the step-space family has no twofloat product path --
+    ``_dyn_chunk_partials`` accumulates qq as ``tf_add_acc`` for real and
+    complex alike, so ``permanent_on_mesh``, ``slice_sums_on_mesh`` and
     ``DistributedPermanent`` agree at every precision mode.
     """
-    A = jnp.asarray(A)
-    n = A.shape[0]
+    planes = _planes(A)
+    n = planes[0].shape[0]
     D = math.prod(mesh.devices.shape)
     total_slices, chunks_per_slice, C = plan_slices(
         n, D, slices_per_device, lanes_per_device)
@@ -218,9 +252,9 @@ def permanent_on_mesh(A, mesh: Mesh, *, precision: str = "dq_acc",
                               NamedSharding(mesh, P_(axes)))
 
     hi, lo = _oneshot_mesh_fn(mesh, spd, chunks_per_slice, C, precision,
-                              backend)(A, dev_slices, dev_live)
-    p0 = jnp.prod(nw_base_vector(A))  # permlint: disable=PL001  # length-n product, shape set by the matrix
-    total = P.tf_add_acc(P.TwoFloat(hi, lo), p0)
+                              backend)(planes, dev_slices, dev_live)
+    p0 = np.prod(nw_base_vector(np.asarray(A)))  # permlint: disable=PL001  # length-n product, shape set by the matrix
+    total = P.tf_add_acc(P.TwoFloat(_join(hi), _join(lo)), p0)
     return P.tf_value(total) * _final_factor(n)
 
 
@@ -236,30 +270,26 @@ def _oneshot_mesh_fn(mesh: Mesh, spd: int, chunks_per_slice: int, C: int,
     PLI104 collective audit: exactly one twofloat psum pair -- two
     ``all-reduce`` instructions per mesh axis at most -- may appear.
     Complex input needs no extra cache key: jit re-specializes on the
-    operand dtype under the same program.
+    number of planes under the same program.
     """
     axes = tuple(mesh.axis_names)
 
-    def device_partials(A_rep, first_chunk):
-        if backend == "pallas":
-            fn = _pallas_device_partials_complex \
-                if jnp.iscomplexobj(A_rep) else _pallas_device_partials
-            return fn(A_rep, first_chunk, chunks_per_slice, C, precision,
-                      vma=frozenset(axes))
-        return _dyn_chunk_partials(A_rep, first_chunk, chunks_per_slice, C,
-                                   precision)
-
-    def body(A_rep, slices_local, live_local):
-        acc = P.TwoFloat(jnp.zeros((), A_rep.dtype),
-                         jnp.zeros((), A_rep.dtype))
+    def body(planes, slices_local, live_local):
+        dtype = planes[0].dtype
+        acc = [P.TwoFloat(jnp.zeros((), dtype), jnp.zeros((), dtype))
+               for _ in planes]
         for i in range(spd):
             first_chunk = slices_local[0, i] * chunks_per_slice
-            parts = device_partials(A_rep, first_chunk)
-            m = live_local[0, i].astype(A_rep.dtype)
-            # permlint: disable=PL001  # parts shape fixed by chunks_per_slice, mesh-invariant
-            h, l = P.two_sum(jnp.sum(parts.hi) * m, jnp.sum(parts.lo) * m)
-            acc = P.tf_add_tf(acc, P.TwoFloat(h, l))
-        hi, lo = acc
+            parts = _device_partials(planes, first_chunk, chunks_per_slice,
+                                     C, precision, backend, None,
+                                     frozenset(axes))
+            m = live_local[0, i].astype(dtype)
+            for p, part in enumerate(parts):
+                # permlint: disable=PL001  # parts shape fixed by chunks_per_slice, mesh-invariant
+                h, l = P.two_sum(jnp.sum(part.hi) * m, jnp.sum(part.lo) * m)
+                acc[p] = P.tf_add_tf(acc[p], P.TwoFloat(h, l))
+        hi = jnp.stack([a.hi for a in acc])
+        lo = jnp.stack([a.lo for a in acc])
         for ax in axes:
             hi = jax.lax.psum(hi, ax)
             lo = jax.lax.psum(lo, ax)
@@ -287,27 +317,26 @@ def _wave_fn(mesh: Mesh, chunks_per_slice: int, chunk_size: int,
     arithmetically-discarded slice-0 program -- under SPMD every device
     executes the same wave program, so the masked work costs no wall
     clock -- and its (hi, lo) contribution is multiplied to exact zero.
+    Outputs are (D, planes): one (hi, lo) column per real plane.
     """
     axes = tuple(mesh.axis_names)
 
-    def body(A_rep, slices_local):
+    def body(planes, slices_local):
         sid = slices_local[0, 0]
         first_chunk = jnp.maximum(sid, 0) * chunks_per_slice
-        if backend == "pallas":
-            fn = _pallas_device_partials_complex \
-                if jnp.iscomplexobj(A_rep) else _pallas_device_partials
-            parts = fn(A_rep, first_chunk, chunks_per_slice, chunk_size,
-                       precision, geometry=geometry, vma=frozenset(axes))
-        else:
-            parts = _dyn_chunk_partials(A_rep, first_chunk,
-                                        chunks_per_slice,
-                                        chunk_size, precision)
+        parts = _device_partials(planes, first_chunk, chunks_per_slice,
+                                 chunk_size, precision, backend, geometry,
+                                 frozenset(axes))
         # sentinel mask: live lanes multiply by exactly 1.0 (identity
         # under IEEE-754), padded lanes by 0.0
-        m = (sid >= 0).astype(A_rep.dtype)
-        # permlint: disable=PL001  # parts shape fixed by chunks_per_slice, mesh-invariant
-        h, l = P.two_sum(jnp.sum(parts.hi) * m, jnp.sum(parts.lo) * m)
-        return h[None], l[None]
+        m = (sid >= 0).astype(planes[0].dtype)
+        hs, ls = [], []
+        for part in parts:
+            # permlint: disable=PL001  # parts shape fixed by chunks_per_slice, mesh-invariant
+            h, l = P.two_sum(jnp.sum(part.hi) * m, jnp.sum(part.lo) * m)
+            hs.append(h)
+            ls.append(l)
+        return jnp.stack(hs)[None], jnp.stack(ls)[None]
 
     return jax.jit(shard_map(body, mesh=mesh,
                              in_specs=(P_(), P_(axes)),
@@ -325,10 +354,10 @@ def slice_sums_on_mesh(A, mesh: Mesh, slice_ids: np.ndarray, *,
     sentinel padding for short waves: their lanes return exact zeros and
     callers must discard them explicitly (``run_campaign`` does) -- no
     already-done slice is ever re-recorded.  Returns (his, los) of shape
-    (D,).  ``geometry`` tunes the per-device kernel launch (pallas
-    backend only; the jnp body has no kernel geometry).
+    (D,), complex for a complex ``A``.  ``geometry`` tunes the per-device
+    kernel launch (pallas backend only; the jnp body has no kernel
+    geometry).
     """
-    A = jnp.asarray(A)
     D = math.prod(mesh.devices.shape)
     slice_ids = np.asarray(slice_ids, dtype=np.int32)
     assert slice_ids.shape == (D,)
@@ -336,65 +365,47 @@ def slice_sums_on_mesh(A, mesh: Mesh, slice_ids: np.ndarray, *,
     dev_slices = jax.device_put(slice_ids.reshape(D, 1),
                                 NamedSharding(mesh, P_(axes)))
     his, los = _wave_fn(mesh, chunks_per_slice, chunk_size,
-                        precision, backend, geometry)(A, dev_slices)
-    return np.asarray(his), np.asarray(los)
+                        precision, backend, geometry)(_planes(A), dev_slices)
+    return _join(his), _join(los)
 
 
-def _pallas_device_partials(A_rep, first_chunk, T: int, C: int,
+def _pallas_device_partials(planes, first_chunk, T: int, C: int,
                             precision: str, geometry: Geometry | None = None,
                             vma=None):
     """Per-device Pallas kernel over the chunk range [first_chunk,
     first_chunk+T); the kernel's u64 lane math consumes the traced base
     index, so the same program serves every device (shard_map-safe).
+    ``planes`` is ``(A,)`` (real kernel) or ``(Ar, Ai)`` (split-plane
+    complex kernel); returns one TwoFloat per plane.
     ``geometry`` tunes lanes (block size within T) and the update window
     (within C); T and C themselves come from the CampaignSpec and are
     part of the campaign's numeric identity, not the tuner's."""
     from ..kernels.ops import pad_matrix, pad_base_vector
-    from ..kernels.ryser_pallas import ryser_pallas_call
-    from .ryser import nw_base_vector
-
-    n = A_rep.shape[0]
-    g = geometry or DEFAULT_GEOMETRY
-    TB = min(g.lanes, T)
-    num_blocks = T // TB
-    Wu = min(g.window, C)
-    A_pad = pad_matrix(A_rep)
-    xb = pad_base_vector(nw_base_vector(A_rep), A_pad.shape[0]).reshape(-1, 1)
-    prec = precision if precision in ("dd", "kahan", "dq_acc", "dq_fast") \
-        else "dq_acc"
-    out = ryser_pallas_call(
-        A_pad, xb, first_chunk, n=n, TB=TB, C=C, Wu=Wu,
-        num_blocks=num_blocks, precision=prec, mode="batched",
-        interpret=True, vma=vma)
-    return P.TwoFloat(out[:, 0], out[:, 1])
-
-
-def _pallas_device_partials_complex(A_rep, first_chunk, T: int, C: int,
-                                    precision: str,
-                                    geometry: Geometry | None = None,
-                                    vma=None):
-    """Split-plane complex analogue of ``_pallas_device_partials``: per-
-    device complex kernel over [first_chunk, first_chunk+T), partials
-    re-packed as a complex TwoFloat so the caller's twofloat psum
-    machinery (componentwise-exact under complex addition) is unchanged."""
-    from ..kernels.ops import split_base_planes, split_matrix_planes
     from ..kernels.ryser_complex import ryser_pallas_call_complex
-    from .ryser import nw_base_vector
+    from ..kernels.ryser_pallas import ryser_pallas_call
 
-    n = A_rep.shape[0]
+    n = planes[0].shape[0]
     g = geometry or DEFAULT_GEOMETRY
     TB = min(g.lanes, T)
     num_blocks = T // TB
     Wu = min(g.window, C)
-    Ar_pad, Ai_pad = split_matrix_planes(A_rep)
-    xbr, xbi = split_base_planes(nw_base_vector(A_rep), Ar_pad.shape[0])
     prec = precision if precision in ("dd", "kahan", "dq_acc", "dq_fast") \
         else "dq_acc"
-    out = ryser_pallas_call_complex(
-        Ar_pad, Ai_pad, xbr, xbi, first_chunk, n=n, TB=TB, C=C, Wu=Wu,
-        num_blocks=num_blocks, precision=prec, interpret=True, vma=vma)
-    return P.TwoFloat(out[:, 0] + 1j * out[:, 2],
-                      out[:, 1] + 1j * out[:, 3])
+    pads = [pad_matrix(A) for A in planes]
+    n_pad = pads[0].shape[0]
+    # padded rows multiply by (1 + 0i): ones in the re plane, zeros in im
+    xbs = [pad_base_vector(nw_base_vector(planes[0]), n_pad)[:, None]]
+    geom = dict(n=n, TB=TB, C=C, Wu=Wu, num_blocks=num_blocks,
+                precision=prec, vma=vma)
+    if len(planes) == 1:
+        out = ryser_pallas_call(pads[0], xbs[0], first_chunk,
+                                mode="batched", **geom)
+        return (P.TwoFloat(out[:, 0], out[:, 1]),)
+    xbs.append(jnp.zeros((n_pad, 1), pads[1].dtype)
+               .at[:n, 0].set(nw_base_vector(planes[1])))
+    out = ryser_pallas_call_complex(*pads, *xbs, first_chunk, **geom)
+    return (P.TwoFloat(out[:, 0], out[:, 1]),
+            P.TwoFloat(out[:, 2], out[:, 3]))
 
 
 # ---------------------------------------------------------------------------
@@ -687,9 +698,10 @@ def run_campaign(A, mesh: Mesh, *, total_slices: int, chunks_per_slice: int,
             progress_cb(state)
 
     hi, lo = state.reduce()
-    p0 = np.prod(np.asarray(nw_base_vector(jnp.asarray(A)))).item()
-    total = P.tf_add_acc(
-        P.TwoFloat(jnp.asarray(hi), jnp.asarray(lo)), jnp.asarray(p0))
+    # the epilogue is host arithmetic: a few adds, and no c128 value
+    # ever reaches the device
+    p0 = np.prod(nw_base_vector(A))  # permlint: disable=PL001  # length-n product, shape set by the matrix
+    total = P.tf_add_acc(P.TwoFloat(np.asarray(hi), np.asarray(lo)), p0)
     # .item(): float for real jobs (the legacy return type), complex
     # for complex jobs
     value = np.asarray(P.tf_value(total)).item() * _final_factor(n)

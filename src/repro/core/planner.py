@@ -344,6 +344,21 @@ def _preprocess_leaves(work: np.ndarray, mplan: MatrixPlan,
     return leaves
 
 
+def _scale_rows(m: np.ndarray) -> tuple[np.ndarray, float]:
+    """Rows scaled by powers of two to 2-norms in [1/2, 1).
+
+    Returns ``(scaled, factor)`` with ``perm(m) == factor * perm(scaled)``.
+    The scaling is exact, so every engine computes the same bits up to
+    the factor wherever its arithmetic neither overflows nor underflows.
+    What it buys is range: Ryser's row-sum products grow like the row
+    norms to the n-th power, and the emulated float64 of a TPU keeps
+    float32's exponent range (about 1e-38 to 3e38) -- the all-ones
+    matrix at n = 32 reaches 16^32 = 2^128 unscaled and overflows there.
+    """
+    _, e = np.frexp(np.linalg.norm(m, axis=1))
+    return m * np.ldexp(1.0, -e)[:, None], float(np.ldexp(1.0, e.sum()))
+
+
 def _density_of(m: np.ndarray) -> float:
     n = m.shape[0]
     return float((m != 0).sum()) / max(1, n * n)
@@ -428,7 +443,11 @@ def build_plan(mats: list[np.ndarray], config: SolverConfig, *,
             if m.shape == (1, 1) and m[0, 0] == 1:
                 mplan.const += leaf.coef
                 continue
-            leaves.append(LeafTask(owner=i, coef=leaf.coef, matrix=m,
+            coef = leaf.coef
+            if m.shape[0] > 2:
+                m, factor = _scale_rows(m)
+                coef = coef * factor
+            leaves.append(LeafTask(owner=i, coef=coef, matrix=m,
                                    route=_route(m, batched)))
 
     # Campaign re-route: any dense/sparse leaf whose step-cost estimate

@@ -317,16 +317,15 @@ def perm_ryser_chunked(A, num_chunks: int = 4096, precision: str = "dq_acc"):
     numerics) -- a ragged straggler served scalar is bit-identical to the
     same leaf served inside a bucket.
     """
+    if jnp.iscomplexobj(A):
+        return _complex_batched(np.asarray(A)[None], num_chunks,
+                                precision)[0]
     A = jnp.asarray(A)
     n = A.shape[0]
     if n == 1:
         return A[0, 0]
     if n == 2:
         return A[0, 0] * A[1, 1] + A[0, 1] * A[1, 0]
-    if jnp.iscomplexobj(A):
-        vr, vi = _batched_complex_jit(jnp.real(A)[None], jnp.imag(A)[None],
-                                      num_chunks, precision)
-        return (vr + 1j * vi)[0]
     return _chunked_jit(A, num_chunks, precision)
 
 
@@ -584,6 +583,8 @@ def perm_ryser_batched(As, num_chunks: int = 4096, precision: str = "dq_acc"):
     and the batched serving loop.  Matches ``perm_ryser_chunked`` per
     element (identical chunk geometry and twofloat outer reduction).
     """
+    if jnp.iscomplexobj(As):
+        return _complex_batched(np.asarray(As), num_chunks, precision)
     As = jnp.asarray(As)
     if As.ndim != 3 or As.shape[1] != As.shape[2]:
         raise ValueError(f"(B, n, n) stack required, got {As.shape}")
@@ -592,8 +593,24 @@ def perm_ryser_batched(As, num_chunks: int = 4096, precision: str = "dq_acc"):
         return As[:, 0, 0]
     if n == 2:
         return (As[:, 0, 0] * As[:, 1, 1] + As[:, 0, 1] * As[:, 1, 0])
-    if jnp.iscomplexobj(As):
-        vr, vi = _batched_complex_jit(jnp.real(As), jnp.imag(As),
-                                      num_chunks, precision)
-        return vr + 1j * vi
     return _batched_jit(As, num_chunks, precision)
+
+
+def _complex_batched(As: np.ndarray, num_chunks: int, precision: str):
+    """A complex (B, n, n) host stack through the split-plane engine.
+
+    The planes are split and joined on the host: no complex value ever
+    reaches the device, because a TPU has no c128 arithmetic (its x64
+    rewriter aborts the process on c128 ops rather than raising).
+    """
+    if As.ndim != 3 or As.shape[1] != As.shape[2]:
+        raise ValueError(f"(B, n, n) stack required, got {As.shape}")
+    n = As.shape[1]
+    if n == 1:
+        return As[:, 0, 0]
+    if n == 2:
+        return As[:, 0, 0] * As[:, 1, 1] + As[:, 0, 1] * As[:, 1, 0]
+    vr, vi = _batched_complex_jit(np.ascontiguousarray(As.real),
+                                  np.ascontiguousarray(As.imag),
+                                  num_chunks, precision)
+    return np.asarray(vr) + 1j * np.asarray(vi)

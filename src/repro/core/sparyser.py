@@ -305,14 +305,15 @@ def perm_sparyser_chunked(sp: SparseMatrix, num_chunks: int = 4096,
     the same leaf served inside a bucket.
     """
     n = sp.n
+    A = np.asarray(sp.to_dense())
     if n == 1:
-        return np.asarray(sp.to_dense()).item()
-    A = jnp.asarray(sp.to_dense())
+        return A.item()
     if n == 2:
-        return np.asarray(A[0, 0] * A[1, 1] + A[0, 1] * A[1, 0]).item()
+        return (A[0, 0] * A[1, 1] + A[0, 1] * A[1, 0]).item()
     if np.iscomplexobj(sp.cvals):
         return perm_sparyser_batched([sp], num_chunks=num_chunks,
                                      precision=precision)[0].item()
+    A = jnp.asarray(A)
     T, C, _ = chunk_geometry(n, num_chunks)
     rows_pad, vals_pad = sp.padded_columns()
     val = sparse_chunked_value(A, jnp.asarray(rows_pad),

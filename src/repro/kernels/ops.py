@@ -17,6 +17,11 @@ distributed runtime (each device runs the kernel over its own chunk
 range; the cross-device reduction is a psum, exactly like the jnp
 engine).
 
+Every launch asks :func:`pallas_interpret` whether to run the Pallas
+interpreter: the CPU interprets, every other platform compiles with
+Mosaic, and a compiled launch refuses 64-bit operands instead of falling
+back to the interpreter or to the jnp engines.
+
 Precision passes through untouched on every route: the kernels implement
 ``dd``/``dq_fast``/``dq_acc``/``kahan`` accumulation and run ``qq`` (no
 in-kernel twofloat product) as ``dd`` -- identically for scalar and
@@ -35,9 +40,8 @@ import jax.numpy as jnp
 from ..core import precision as P
 from ..core.ryser import nw_base_vector, _final_factor
 from ..core.stepspace import DEFAULT_GEOMETRY, Geometry
-from .ryser_pallas import ryser_pallas_call, ryser_pallas_call_batched
 
-__all__ = ["Geometry", "DEFAULT_GEOMETRY",
+__all__ = ["Geometry", "DEFAULT_GEOMETRY", "pallas_interpret",
            "permanent_pallas", "permanent_pallas_batched",
            "permanent_pallas_sparse", "permanent_pallas_sparse_batched",
            "sparse_batched_values_pallas",
@@ -45,6 +49,36 @@ __all__ = ["Geometry", "DEFAULT_GEOMETRY",
            "pad_base_vector", "split_matrix_planes", "split_base_planes"]
 
 _SUBLANE = 8  # f32 sublane quantum on TPU
+
+
+def pallas_interpret(*operands, interpret: bool | None = None) -> bool:
+    """Whether a Pallas launch over ``operands`` runs in interpret mode.
+
+    The platform decides: the Pallas interpreter on CPU, Mosaic on every
+    other platform.  Mosaic lowers no 64-bit float, so a compiled launch
+    with a float64/complex128 operand raises ``TypeError`` -- it neither
+    interprets nor drops to the jnp engines; running the kernels on a
+    chip needs f32-base numerics (ROADMAP.md, Reach: "f32-base numerics
+    end to end").  ``interpret`` overrides the platform where a caller
+    must choose: an explicit ``True`` (CPU tuning runs), or ``False`` to
+    compile for a described chip from a CPU host.
+    """
+    platform = jax.default_backend()
+    if interpret is None:
+        interpret = platform == "cpu"
+    elif interpret and platform != "cpu":
+        raise ValueError(f"Pallas interpret mode is for the CPU only; this "
+                         f"process runs on {platform}")
+    wide = sorted({str(x.dtype) for x in operands
+                   if jnp.issubdtype(x.dtype, jnp.inexact)
+                   and jnp.finfo(x.dtype).bits == 64})
+    if not interpret and wide:
+        raise TypeError(
+            f"Pallas kernels compile with Mosaic on {platform}, which has "
+            f"no 64-bit float types (got {', '.join(wide)}); use "
+            f"backend='jnp', or see ROADMAP.md, Reach: 'f32-base numerics "
+            f"end to end'")
+    return interpret
 
 
 def pad_matrix(A, n_pad: int | None = None):
@@ -104,9 +138,11 @@ def block_partials_pallas(A, *, dev_chunk_base: int = 0,
                           num_blocks: int | None = None,
                           geometry: Geometry | None = None,
                           precision: str = "dq_acc",
-                          mode: str = "baseline", interpret: bool = True):
+                          mode: str = "baseline",
+                          interpret: bool | None = None):
     """Run the kernel over ``num_blocks`` blocks starting at chunk
     ``dev_chunk_base``; returns (num_blocks, 2) (hi, lo) partials."""
+    from .ryser_pallas import ryser_pallas_call
     A = jnp.asarray(A)
     n = A.shape[0]
     TB, C, Wu, full_blocks = (geometry or DEFAULT_GEOMETRY).kernel_geometry(n)
@@ -174,7 +210,7 @@ def _reduce_complex(out, xbs, n: int, batched: bool):
 
 
 def _pallas_values(As, *, batched: bool, precision: str, mode: str,
-                   geometry: Geometry, interpret: bool):
+                   geometry: Geometry, interpret: bool | None):
     """One traced body behind every public dense pallas entry.
 
     ``As`` is (n, n) (``batched=False``) or (B, n, n); real input launches
@@ -183,6 +219,9 @@ def _pallas_values(As, *, batched: bool, precision: str, mode: str,
     shared.  ``geometry`` is the single frozen knob bundle the tuner
     injects; its requested sizes are clamped to n's step space here.
     """
+    from .ryser_complex import (ryser_pallas_call_complex,
+                                ryser_pallas_call_complex_batched)
+    from .ryser_pallas import ryser_pallas_call, ryser_pallas_call_batched
     n = As.shape[-1]
     TB, C, Wu, blocks = geometry.kernel_geometry(n)
 
@@ -199,8 +238,6 @@ def _pallas_values(As, *, batched: bool, precision: str, mode: str,
                 interpret=interpret)[None]
         return _reduce_real(out, xbs, n, batched)
 
-    from .ryser_complex import (ryser_pallas_call_complex,
-                                ryser_pallas_call_complex_batched)
     Ar_pads, Ai_pads, xbr, xbi, xbs = _prep_complex(As, batched)
     if batched:
         out = ryser_pallas_call_complex_batched(
@@ -222,7 +259,7 @@ def _pallas_values_jit(As, batched, precision, mode, geometry, interpret):
 
 def _pallas_sparse_values(A_stack, rows_stack, vals_stack, *, batched: bool,
                           precision: str, geometry: Geometry,
-                          interpret: bool):
+                          interpret: bool | None):
     """Sparse arm of the dispatch helper (SpaRyser on Pallas).
 
     Mirrors ``_pallas_values`` over the padded-CCS layout of
@@ -287,7 +324,7 @@ def _pallas_sparse_values_jit(A_stack, rows_stack, vals_stack, batched,
 def sparse_batched_values_pallas(A_stack, rows_stack, vals_stack, *,
                                  precision: str = "dq_acc",
                                  geometry: Geometry | None = None,
-                                 interpret: bool = True):
+                                 interpret: bool | None = None):
     """Traced (B,) sparse kernel values of a packed padded-CCS stack.
 
     The un-jitted traced body behind ``permanent_pallas_sparse_batched``,
@@ -303,7 +340,7 @@ def sparse_batched_values_pallas(A_stack, rows_stack, vals_stack, *,
 
 def permanent_pallas(A, *, precision: str = "dq_acc", mode: str = "baseline",
                      geometry: Geometry | None = None,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """perm(A) via the Pallas kernel (full iteration space, one device).
 
     Complex matrices run the split re/im kernel (window-batched mode)."""
@@ -316,13 +353,14 @@ def permanent_pallas(A, *, precision: str = "dq_acc", mode: str = "baseline",
     if jnp.iscomplexobj(A):
         mode = "batched"             # the split-plane kernel's only mode
     return _pallas_values_jit(A, False, precision, mode,
-                              geometry or DEFAULT_GEOMETRY, interpret)
+                              geometry or DEFAULT_GEOMETRY,
+                              pallas_interpret(A, interpret=interpret))
 
 
 def permanent_pallas_batched(As, *, precision: str = "dq_acc",
                              mode: str = "batched",
                              geometry: Geometry | None = None,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """perm of a (B, n, n) stack via ONE batch-grid kernel launch.
 
     The grid is (batch, block): every matrix's full iteration space runs
@@ -345,12 +383,13 @@ def permanent_pallas_batched(As, *, precision: str = "dq_acc",
     elif mode not in ("baseline", "batched"):
         raise ValueError(f"batch grid supports baseline|batched, got {mode}")
     return _pallas_values_jit(As, True, precision, mode,
-                              geometry or DEFAULT_GEOMETRY, interpret)
+                              geometry or DEFAULT_GEOMETRY,
+                              pallas_interpret(As, interpret=interpret))
 
 
 def permanent_pallas_sparse(sp, *, precision: str = "dq_acc",
                             geometry: Geometry | None = None,
-                            interpret: bool = True):
+                            interpret: bool | None = None):
     """perm of one ``sparyser.SparseMatrix`` via the SpaRyser kernel.
 
     The scalar sparse entry the executor's pallas backend dispatches to:
@@ -367,12 +406,13 @@ def permanent_pallas_sparse(sp, *, precision: str = "dq_acc",
     rows, vals = sp.padded_columns()
     return _pallas_sparse_values_jit(A, jnp.asarray(rows),
                                      jnp.asarray(vals), False, precision,
-                                     geometry or DEFAULT_GEOMETRY, interpret)
+                                     geometry or DEFAULT_GEOMETRY,
+                                     pallas_interpret(A, interpret=interpret))
 
 
 def permanent_pallas_sparse_batched(sps, *, precision: str = "dq_acc",
                                     geometry: Geometry | None = None,
-                                    interpret: bool = True):
+                                    interpret: bool | None = None):
     """perms of a same-size ``SparseMatrix`` bucket via ONE (batch, block)
     grid SpaRyser kernel launch.
 
@@ -394,4 +434,5 @@ def permanent_pallas_sparse_batched(sps, *, precision: str = "dq_acc",
                                      jnp.asarray(rows_stack),
                                      jnp.asarray(vals_stack), True,
                                      precision, geometry or DEFAULT_GEOMETRY,
-                                     interpret)
+                                     pallas_interpret(A_stack,
+                                                      interpret=interpret))

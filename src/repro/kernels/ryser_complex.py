@@ -35,10 +35,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from ..utils.compat import shape_dtype_struct
 from . import u64emu as U
-from .ryser_pallas import (_accum_add, _accum_make, _cumsig_host,
-                           _signed_const_schedule, device_base_u32)
+from .ops import pallas_interpret
+from .ryser_pallas import (_Z, _accum_add, _accum_make, _cumsig_host, _mm,
+                           _partials_out, _signed_const_schedule,
+                           _write_partials, device_base_u32)
 
 __all__ = ["ryser_pallas_call_complex", "ryser_pallas_call_complex_batched"]
 
@@ -73,12 +74,12 @@ def _ryser_block_cx(i, Ar, Ai, xbr, xbi, c0, dev_base, *, n: int, n_pad: int,
     start64 = U.u64_shl(chunk64, k)
 
     gbits = U.u64_gray(start64)
-    rows = [U.u64_bit(gbits, np.uint32(j)).astype(dtype) if j < n
+    rows = [U.to_float(U.u64_bit(gbits, np.uint32(j)), dtype) if j < n
             else jnp.zeros((TB,), dtype) for j in range(n_pad)]
     Gb = jnp.stack(rows, axis=0)
     dd = (((1,), (0,)), ((), ()))
-    Xr = xbr + jax.lax.dot_general(Ar, Gb, dd, preferred_element_type=dtype)
-    Xi = xbi + jax.lax.dot_general(Ai, Gb, dd, preferred_element_type=dtype)
+    Xr = xbr + _mm(Ar, Gb, dtype)
+    Xi = xbi + _mm(Ai, Gb, dtype)
 
     sched = _signed_const_schedule(Wu)
     space_m1 = U.u64_from_int(space - 1, like=lane)
@@ -90,13 +91,13 @@ def _ryser_block_cx(i, Ar, Ai, xbr, xbi, c0, dev_base, *, n: int, n_pad: int,
         Xr, Xi, acc_r, acc_i = carry
         macro64 = U.u64_add_u32(start64,
                                 m.astype(jnp.uint32) * np.uint32(Wu))
-        bitk = U.u64_bit(macro64, np.uint32(kw)).astype(dtype)
+        bitk = U.to_float(U.u64_bit(macro64, np.uint32(kw)), dtype)
 
         # window-batched states: D = A @ cumsig for both planes
-        Dr = jax.lax.dot_general(Ar, C0, dd, preferred_element_type=dtype)
-        Di = jax.lax.dot_general(Ai, C0, dd, preferred_element_type=dtype)
-        cmr = jax.lax.dynamic_slice_in_dim(Ar, kw - 1, 1, 1)
-        cmi = jax.lax.dynamic_slice_in_dim(Ai, kw - 1, 1, 1)
+        Dr = _mm(Ar, C0, dtype)
+        Di = _mm(Ai, C0, dtype)
+        cmr = Ar[:, kw - 1:kw]
+        cmi = Ai[:, kw - 1:kw]
         s_mid = sched[mid_idx][1] if mid_idx is not None else 0
         corr = (float(-2.0 * s_mid) * bitk)[None, :]
         for idx, (j, s, is_mid, parity) in enumerate(sched):
@@ -117,13 +118,11 @@ def _ryser_block_cx(i, Ar, Ai, xbr, xbi, c0, dev_base, *, n: int, n_pad: int,
         # boundary step
         gb64 = U.u64_add_u32(macro64, np.uint32(Wu))
         jb = U.u64_ctz(gb64)
-        sb = 2 * U.u64_bit(U.u64_gray(gb64), jb).astype(dtype) - 1
+        sb = 2 * U.to_float(U.u64_bit(U.u64_gray(gb64), jb), dtype) - 1
         live = U.u64_leq(gb64, space_m1).astype(dtype)
         onehot = (row_iota == jb[None, :].astype(jnp.uint32)).astype(dtype)
-        colr = jax.lax.dot_general(Ar, onehot, dd,
-                                   preferred_element_type=dtype)
-        coli = jax.lax.dot_general(Ai, onehot, dd,
-                                   preferred_element_type=dtype)
+        colr = _mm(Ar, onehot, dtype)
+        coli = _mm(Ai, onehot, dtype)
         Xr = Xr + colr * (sb * live)[None, :]
         Xi = Xi + coli * (sb * live)[None, :]
         pr, pi = _cprod(Xr, Xi, n_pad)
@@ -138,7 +137,7 @@ def _ryser_block_cx(i, Ar, Ai, xbr, xbi, c0, dev_base, *, n: int, n_pad: int,
                                           (Xr, Xi, acc_r, acc_i))
     else:
         Xr, Xi, acc_r, acc_i = jax.lax.fori_loop(
-            0, M, macro_body, (Xr, Xi, acc_r, acc_i))
+            jnp.int32(0), jnp.int32(M), macro_body, (Xr, Xi, acc_r, acc_i))
 
     zero = jnp.zeros((), dtype)
     keep_err = precision in ("dq_acc", "dq_fast")
@@ -151,16 +150,12 @@ def _ryser_block_cx(i, Ar, Ai, xbr, xbi, c0, dev_base, *, n: int, n_pad: int,
 
 def _ryser_kernel_cx(base_hi_ref, base_lo_ref, Ar_ref, Ai_ref, xbr_ref,
                      xbi_ref, c0_ref, out_ref, **geom):
-    """Single-matrix kernel: grid = (num_blocks,); writes (1, 4) partials."""
+    """Single-matrix kernel: grid = (num_blocks,); writes the 4 partials."""
     dev = (base_hi_ref[0, 0].astype(jnp.uint32),
            base_lo_ref[0, 0].astype(jnp.uint32))
-    hr, er, hi, ei = _ryser_block_cx(
+    _write_partials(out_ref, _ryser_block_cx(
         pl.program_id(0), Ar_ref[...], Ai_ref[...], xbr_ref[...],
-        xbi_ref[...], c0_ref[...], dev, **geom)
-    out_ref[0, 0] = hr
-    out_ref[0, 1] = er
-    out_ref[0, 2] = hi
-    out_ref[0, 3] = ei
+        xbi_ref[...], c0_ref[...], dev, **geom))
 
 
 def _ryser_kernel_cx_batched(Ar_ref, Ai_ref, xbr_ref, xbi_ref, c0_ref,
@@ -169,25 +164,23 @@ def _ryser_kernel_cx_batched(Ar_ref, Ai_ref, xbr_ref, xbi_ref, c0_ref,
     whole stack.  Block b of the plane stacks is selected by the
     BlockSpec; the chunk base is 0 (each matrix owns its full space)."""
     zero = jnp.uint32(0)
-    hr, er, hi, ei = _ryser_block_cx(
+    _write_partials(out_ref, _ryser_block_cx(
         pl.program_id(1), Ar_ref[0], Ai_ref[0], xbr_ref[0], xbi_ref[0],
-        c0_ref[...], (zero, zero), **geom)
-    out_ref[0, 0, 0] = hr
-    out_ref[0, 0, 1] = er
-    out_ref[0, 0, 2] = hi
-    out_ref[0, 0, 3] = ei
+        c0_ref[...], (zero, zero), **geom))
 
 
 def ryser_pallas_call_complex(Ar_pad, Ai_pad, xbr, xbi,
                               dev_chunk_base, *, n: int, TB: int,
                               C: int, Wu: int, num_blocks: int,
                               precision: str = "dq_acc",
-                              interpret: bool = True, vma=None):
+                              interpret: bool | None = None, vma=None):
     """(num_blocks, 4) partials: (re_hi, re_err, im_hi, im_err).
 
     ``dev_chunk_base`` may be a host int or a traced scalar (the
     distributed shard_map path), exactly like the real kernel.
     """
+    interpret = pallas_interpret(Ar_pad, Ai_pad, xbr, xbi,
+                                 interpret=interpret)
     n_pad = Ar_pad.shape[0]
     dtype = Ar_pad.dtype
     space = 1 << (n - 1)
@@ -196,7 +189,8 @@ def ryser_pallas_call_complex(Ar_pad, Ai_pad, xbr, xbi,
     kernel = functools.partial(
         _ryser_kernel_cx, n=n, n_pad=n_pad, TB=TB, C=C, Wu=Wu, space=space,
         precision=precision, dtype=dtype)
-    rep = lambda i: (0, 0)
+    rep = lambda i: (_Z, _Z)
+    out_spec, out_shape = _partials_out(num_blocks, dtype, vma=vma)
     return pl.pallas_call(
         kernel,
         grid=(num_blocks,),
@@ -207,17 +201,17 @@ def ryser_pallas_call_complex(Ar_pad, Ai_pad, xbr, xbi,
             pl.BlockSpec((n_pad, 1), rep), pl.BlockSpec((n_pad, 1), rep),
             pl.BlockSpec(c0.shape, rep),
         ],
-        out_specs=pl.BlockSpec((1, 4), lambda i: (i, 0)),
-        out_shape=shape_dtype_struct((num_blocks, 4), dtype, vma=vma),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(base_hi, base_lo, Ar_pad, Ai_pad, xbr, xbi, c0)
+    )(base_hi, base_lo, Ar_pad, Ai_pad, xbr, xbi, c0)[:, 0, :4]
 
 
 def ryser_pallas_call_complex_batched(Ar_pads, Ai_pads, xbr_pads, xbi_pads,
                                       *, n: int, TB: int, C: int, Wu: int,
                                       num_blocks: int,
                                       precision: str = "dq_acc",
-                                      interpret: bool = True):
+                                      interpret: bool | None = None):
     """Launch ONE split-plane kernel over a (B, n_pad, n_pad) plane pair:
     grid is (batch, block), so a single ``pallas_call`` covers every
     matrix's full 2^{n-1} step space -- the complex analogue of
@@ -226,6 +220,8 @@ def ryser_pallas_call_complex_batched(Ar_pads, Ai_pads, xbr_pads, xbi_pads,
     Returns (B, num_blocks, 4) (re_hi, re_err, im_hi, im_err) partials
     (base g=0 terms NOT included).
     """
+    interpret = pallas_interpret(Ar_pads, Ai_pads, xbr_pads, xbi_pads,
+                                 interpret=interpret)
     B, n_pad, _ = Ar_pads.shape
     dtype = Ar_pads.dtype
     space = 1 << (n - 1)
@@ -235,17 +231,18 @@ def ryser_pallas_call_complex_batched(Ar_pads, Ai_pads, xbr_pads, xbi_pads,
         _ryser_kernel_cx_batched, n=n, n_pad=n_pad, TB=TB, C=C, Wu=Wu,
         space=space, precision=precision, dtype=dtype)
 
+    out_spec, out_shape = _partials_out(num_blocks, dtype, batch=B)
     return pl.pallas_call(
         kernel,
         grid=(B, num_blocks),
         in_specs=[
-            pl.BlockSpec((1, n_pad, n_pad), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, n_pad, n_pad), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, n_pad, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, n_pad, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec(c0.shape, lambda b, i: (0, 0)),
+            pl.BlockSpec((1, n_pad, n_pad), lambda b, i: (b, _Z, _Z)),
+            pl.BlockSpec((1, n_pad, n_pad), lambda b, i: (b, _Z, _Z)),
+            pl.BlockSpec((1, n_pad, 1), lambda b, i: (b, _Z, _Z)),
+            pl.BlockSpec((1, n_pad, 1), lambda b, i: (b, _Z, _Z)),
+            pl.BlockSpec(c0.shape, lambda b, i: (_Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 4), lambda b, i: (b, i, 0)),
-        out_shape=shape_dtype_struct((B, num_blocks, 4), dtype),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(Ar_pads, Ai_pads, xbr_pads, xbi_pads, c0)
+    )(Ar_pads, Ai_pads, xbr_pads, xbi_pads, c0)[:, :, 0, :4]

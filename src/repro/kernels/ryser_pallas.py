@@ -47,9 +47,41 @@ from ..core import gray as G
 from ..core.stepspace import kernel_geometry
 from ..utils.compat import shape_dtype_struct
 from . import u64emu as U
+from .ops import pallas_interpret
 
 __all__ = ["ryser_pallas_call", "ryser_pallas_call_batched",
            "kernel_geometry", "device_base_u32"]
+
+# Per-block partials leave a kernel as one whole (8, 128) tile (an f32
+# vreg) with the values in the first lanes of row 0: Mosaic tiles the
+# last two dims of every block by (8, 128), and a (1, 2) block does not
+# lower.  The wrappers slice the values back out.
+_TILE = (8, 128)
+
+# Block index maps return int32 zeros: under x64 a Python 0 lowers as an
+# i64 constant, which Mosaic cannot legalize.
+_Z = np.int32(0)
+
+
+def _write_partials(out_ref, values):
+    """Store ``values`` (scalars) into lanes 0.. of row 0 of the block's
+    tile, zeros elsewhere; the whole tile is written at once."""
+    row = jax.lax.broadcasted_iota(jnp.int32, _TILE, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, _TILE, 1)
+    tile = jnp.zeros(_TILE, out_ref.dtype)
+    for k, v in enumerate(values):
+        tile = jnp.where((row == 0) & (lane == k), v, tile)
+    out_ref[...] = tile.reshape(out_ref.shape)
+
+
+def _partials_out(num_blocks: int, dtype, batch: int | None = None,
+                  vma=None):
+    """(out_spec, out_shape) of the per-block partial tiles."""
+    if batch is None:
+        return (pl.BlockSpec((1,) + _TILE, lambda i: (i, _Z, _Z)),
+                shape_dtype_struct((num_blocks,) + _TILE, dtype, vma=vma))
+    return (pl.BlockSpec((1, 1) + _TILE, lambda b, i: (b, i, _Z, _Z)),
+            shape_dtype_struct((batch, num_blocks) + _TILE, dtype))
 
 
 def device_base_u32(dev_chunk_base):
@@ -96,6 +128,24 @@ def _signed_const_schedule(Wu: int):
         parity = w & 1
         out.append((j, s, is_mid, parity))
     return out
+
+
+def _mm(a, b, dtype):
+    """``a @ b`` at full precision.  On TPU a float32 dot at default
+    precision runs one bfloat16 pass on the MXU (about 3 significant
+    digits), which the kernels' float32 values cannot afford."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=dtype)
+
+
+def _rprod(X):
+    """Product over the rows of an (n_pad, TB) state, as a fixed chain
+    (Mosaic has no reduce_prod)."""
+    p = X[0]
+    for i in range(1, X.shape[0]):
+        p = p * X[i]
+    return p
 
 
 def _accum_make(dtype, shape):
@@ -185,12 +235,11 @@ def _ryser_block(i, A, xb, c0, dev_base, *,
     rows = []
     for j in range(n_pad):
         if j < n:
-            rows.append(U.u64_bit(gbits_start, np.uint32(j)).astype(dtype))
+            rows.append(U.to_float(U.u64_bit(gbits_start, np.uint32(j)), dtype))
         else:
             rows.append(jnp.zeros((TB,), dtype))
     Gb = jnp.stack(rows, axis=0)                     # (n_pad, TB)
-    X = xb + jax.lax.dot_general(
-        A, Gb, (((1,), (0,)), ((), ())), preferred_element_type=dtype)
+    X = xb + _mm(A, Gb, dtype)
 
     sched = _signed_const_schedule(Wu)
     space_m1 = U.u64_from_int(space - 1, like=lane)
@@ -207,37 +256,36 @@ def _ryser_block(i, A, xb, c0, dev_base, *,
         m_u = m.astype(jnp.uint32) * np.uint32(Wu)
         macro64 = U.u64_add_u32(start64, m_u)
         # per-lane bit kw of the macro base (mid-step sign correction)
-        bitk = U.u64_bit(macro64, np.uint32(kw)).astype(dtype)  # (TB,)
+        bitk = U.to_float(U.u64_bit(macro64, np.uint32(kw)), dtype)  # (TB,)
         mid_flip = 1 - 2 * bitk                                  # +-1
 
         if mode == "baseline":
             for (j, s, is_mid, parity) in sched:
-                colj = jax.lax.dynamic_slice_in_dim(A, j, 1, 1)  # (n_pad,1)
+                colj = A[:, j:j + 1]  # (n_pad,1)
                 if is_mid:
                     slane = (s * mid_flip)[None, :]              # (1, TB)
                     X = X + colj * slane
                 else:
                     X = X + colj * float(s)
-                prod = jnp.prod(X, axis=0)  # permlint: disable=PL001  # fixed-axis lane product inside one block
+                prod = _rprod(X)
                 term = -prod if parity else prod
                 acc = _accum_add(acc, term, precision)
         elif mode == "schedmat":
             # beyond-paper: per-step signed column precomputed (C0 = A@Sel);
             # inner step = one add + product; mid step adds one correction
-            col_mid = jax.lax.dynamic_slice_in_dim(A, kw - 1, 1, 1) \
+            col_mid = A[:, kw - 1:kw] \
                 if kw >= 1 else jnp.zeros((n_pad, 1), dtype)
             for idx, (j, s, is_mid, parity) in enumerate(sched):
                 X = X + C0[:, idx][:, None]
                 if is_mid:
                     X = X + col_mid * (float(-2.0 * s) * bitk)[None, :]
-                prod = jnp.prod(X, axis=0)  # permlint: disable=PL001  # fixed-axis lane product inside one block
+                prod = _rprod(X)
                 term = -prod if parity else prod
                 acc = _accum_add(acc, term, precision)
         else:
             # window-batched: states from one shared matmul, X never written
-            D = jax.lax.dot_general(A, C0, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=dtype)  # (n_pad,Wu-1)
-            col_mid = jax.lax.dynamic_slice_in_dim(A, kw - 1, 1, 1) if kw >= 1 \
+            D = _mm(A, C0, dtype)  # (n_pad,Wu-1)
+            col_mid = A[:, kw - 1:kw] if kw >= 1 \
                 else jnp.zeros((n_pad, 1), dtype)
             # lanes with bitk=1 need mid sign -s i.e. subtract 2*s*col_mid
             s_mid = sched[mid_idx][1] if mid_idx is not None else 0
@@ -246,7 +294,7 @@ def _ryser_block(i, A, xb, c0, dev_base, *,
                 state = X + D[:, idx][:, None]
                 if mid_idx is not None and idx >= mid_idx:
                     state = state + corr
-                prod = jnp.prod(state, axis=0)  # permlint: disable=PL001  # fixed-axis lane product inside one block
+                prod = _rprod(state)
                 term = -prod if parity else prod
                 acc = _accum_add(acc, term, precision)
             # advance X to the last inner state for the boundary step
@@ -257,14 +305,13 @@ def _ryser_block(i, A, xb, c0, dev_base, *,
         # ---- boundary step w = Wu (per-lane column via one-hot MXU) ----
         gb64 = U.u64_add_u32(macro64, np.uint32(Wu))
         jb = U.u64_ctz(gb64)                                    # (TB,)
-        sign_bit = U.u64_bit(U.u64_gray(gb64), jb).astype(dtype)
+        sign_bit = U.to_float(U.u64_bit(U.u64_gray(gb64), jb), dtype)
         sb = 2 * sign_bit - 1                                   # (TB,)
         live = U.u64_leq(gb64, space_m1).astype(dtype)          # (TB,)
         onehot = (row_iota == jb[None, :].astype(jnp.uint32)).astype(dtype)
-        colb = jax.lax.dot_general(A, onehot, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=dtype)
+        colb = _mm(A, onehot, dtype)
         X = X + colb * (sb * live)[None, :]
-        prod = jnp.prod(X, axis=0)  # permlint: disable=PL001  # fixed-axis lane product inside one block
+        prod = _rprod(X)
         # (-1)^{g_boundary} == (-1)^{Wu} == +1 (Wu is even)
         acc = _accum_add(acc, prod * live, precision)
         return (X, acc)
@@ -273,7 +320,8 @@ def _ryser_block(i, A, xb, c0, dev_base, *,
     if M == 1:
         X, acc = macro_body(jnp.int32(0), (X, acc0))
     else:
-        X, acc = jax.lax.fori_loop(0, M, macro_body, (X, acc0))
+        X, acc = jax.lax.fori_loop(jnp.int32(0), jnp.int32(M), macro_body,
+                                   (X, acc0))
 
     hi, lo = _accum_value(acc, precision)
     # permlint: disable=PL001  # in-kernel lane reduce, under the 1e-9 kernel contract
@@ -282,13 +330,12 @@ def _ryser_block(i, A, xb, c0, dev_base, *,
 
 def _ryser_kernel(base_hi_ref, base_lo_ref, A_ref, xb_ref, c0_ref, out_ref,
                   **geom):
-    """Single-matrix kernel: grid = (num_blocks,); writes (1, 2) partials."""
+    """Single-matrix kernel: grid = (num_blocks,); writes (hi, lo)."""
     dev_base = (base_hi_ref[0, 0].astype(jnp.uint32),
                 base_lo_ref[0, 0].astype(jnp.uint32))
-    hi, lo = _ryser_block(pl.program_id(0), A_ref[...], xb_ref[...],
-                          c0_ref[...], dev_base, **geom)
-    out_ref[0, 0] = hi
-    out_ref[0, 1] = lo
+    _write_partials(out_ref, _ryser_block(
+        pl.program_id(0), A_ref[...], xb_ref[...], c0_ref[...], dev_base,
+        **geom))
 
 
 def _ryser_kernel_batched(A_ref, xb_ref, c0_ref, out_ref, **geom):
@@ -296,18 +343,18 @@ def _ryser_kernel_batched(A_ref, xb_ref, c0_ref, out_ref, **geom):
     whole stack.  Block b of the A/xb stacks is selected by the BlockSpec;
     the chunk base is 0 (each matrix owns its full iteration space)."""
     zero = jnp.uint32(0)
-    hi, lo = _ryser_block(pl.program_id(1), A_ref[0], xb_ref[0],
-                          c0_ref[...], (zero, zero), **geom)
-    out_ref[0, 0, 0] = hi
-    out_ref[0, 0, 1] = lo
+    _write_partials(out_ref, _ryser_block(
+        pl.program_id(1), A_ref[0], xb_ref[0], c0_ref[...], (zero, zero),
+        **geom))
 
 
 def ryser_pallas_call(A_pad, x_base_pad, dev_chunk_base, *,
                       n: int, TB: int, C: int, Wu: int, num_blocks: int,
                       precision: str = "dq_acc", mode: str = "baseline",
-                      interpret: bool = True, vma=None):
+                      interpret: bool | None = None, vma=None):
     """Launch the kernel over ``num_blocks`` blocks; returns (blocks, 2)
     per-block (hi, lo) partial sums (base g=0 term NOT included)."""
+    interpret = pallas_interpret(A_pad, x_base_pad, interpret=interpret)
     n_pad = A_pad.shape[0]
     dtype = A_pad.dtype
     space = 1 << (n - 1)
@@ -323,26 +370,28 @@ def ryser_pallas_call(A_pad, x_base_pad, dev_chunk_base, *,
         _ryser_kernel, n=n, n_pad=n_pad, TB=TB, C=C, Wu=Wu, space=space,
         precision=precision, mode=mode, dtype=dtype)
 
+    out_spec, out_shape = _partials_out(num_blocks, dtype, vma=vma)
     return pl.pallas_call(
         kernel,
         grid=(num_blocks,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n_pad, n_pad), lambda i: (0, 0)),
-            pl.BlockSpec((n_pad, 1), lambda i: (0, 0)),
-            pl.BlockSpec(c0.shape, lambda i: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i: (_Z, _Z)),
+            pl.BlockSpec((1, 1), lambda i: (_Z, _Z)),
+            pl.BlockSpec((n_pad, n_pad), lambda i: (_Z, _Z)),
+            pl.BlockSpec((n_pad, 1), lambda i: (_Z, _Z)),
+            pl.BlockSpec(c0.shape, lambda i: (_Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        out_shape=shape_dtype_struct((num_blocks, 2), dtype, vma=vma),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(base_hi, base_lo, A_pad, x_base_pad, c0)
+    )(base_hi, base_lo, A_pad, x_base_pad, c0)[:, 0, :2]
 
 
 def ryser_pallas_call_batched(A_pads, x_base_pads, *,
                               n: int, TB: int, C: int, Wu: int,
                               num_blocks: int, precision: str = "dq_acc",
-                              mode: str = "batched", interpret: bool = True):
+                              mode: str = "batched",
+                              interpret: bool | None = None):
     """Launch ONE kernel over a (B, n_pad, n_pad) stack: grid is
     (batch, block), so a single ``pallas_call`` covers every matrix's full
     2^{n-1} step space.  Returns (B, num_blocks, 2) (hi, lo) partials
@@ -354,6 +403,7 @@ def ryser_pallas_call_batched(A_pads, x_base_pads, *,
     """
     if mode not in ("baseline", "batched"):
         raise ValueError(f"batch grid supports baseline|batched, got {mode}")
+    interpret = pallas_interpret(A_pads, x_base_pads, interpret=interpret)
     B, n_pad, _ = A_pads.shape
     dtype = A_pads.dtype
     space = 1 << (n - 1)
@@ -364,15 +414,16 @@ def ryser_pallas_call_batched(A_pads, x_base_pads, *,
         _ryser_kernel_batched, n=n, n_pad=n_pad, TB=TB, C=C, Wu=Wu,
         space=space, precision=precision, mode=mode, dtype=dtype)
 
+    out_spec, out_shape = _partials_out(num_blocks, dtype, batch=B)
     return pl.pallas_call(
         kernel,
         grid=(B, num_blocks),
         in_specs=[
-            pl.BlockSpec((1, n_pad, n_pad), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, n_pad, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec(c0.shape, lambda b, i: (0, 0)),
+            pl.BlockSpec((1, n_pad, n_pad), lambda b, i: (b, _Z, _Z)),
+            pl.BlockSpec((1, n_pad, 1), lambda b, i: (b, _Z, _Z)),
+            pl.BlockSpec(c0.shape, lambda b, i: (_Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 2), lambda b, i: (b, i, 0)),
-        out_shape=shape_dtype_struct((B, num_blocks, 2), dtype),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(A_pads, x_base_pads, c0)
+    )(A_pads, x_base_pads, c0)[:, :, 0, :2]

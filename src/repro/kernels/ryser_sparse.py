@@ -56,11 +56,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from ..utils.compat import shape_dtype_struct
 from . import u64emu as U
+from .ops import pallas_interpret
 from .ryser_complex import _cprod
-from .ryser_pallas import (_accum_add, _accum_make, _accum_value,
-                           _cumsig_host, _signed_const_schedule,
+from .ryser_pallas import (_Z, _accum_add, _accum_make, _accum_value,
+                           _cumsig_host, _mm, _partials_out, _rprod,
+                           _signed_const_schedule, _write_partials,
                            device_base_u32)
 
 __all__ = ["ryser_sparse_pallas_call", "ryser_sparse_pallas_call_batched",
@@ -82,7 +83,7 @@ def _chunk_starts(i, dev_base, TB: int, C: int):
 def _gray_init_bits(start64, n: int, n_pad: int, TB: int, dtype):
     """(n_pad, TB) Gray-code bit matrix of the chunk start steps."""
     gbits = U.u64_gray(start64)
-    rows = [U.u64_bit(gbits, np.uint32(j)).astype(dtype) if j < n
+    rows = [U.to_float(U.u64_bit(gbits, np.uint32(j)), dtype) if j < n
             else jnp.zeros((TB,), dtype) for j in range(n_pad)]
     return jnp.stack(rows, axis=0)
 
@@ -101,9 +102,8 @@ def _scatter_low_columns(rows, vals, kw: int, n_pad: int, dtype):
     cols = []
     for j in range(kw):
         onehot = (riota == rows[j][None, :].astype(jnp.int32)).astype(dtype)
-        cols.append(jax.lax.dot_general(
-            onehot, vals[j][:, None].astype(dtype), (((1,), (0,)), ((), ())),
-            preferred_element_type=dtype))               # (n_pad, 1)
+        cols.append(_mm(onehot, vals[j][:, None].astype(dtype),
+                        dtype))                          # (n_pad, 1)
     return jnp.concatenate(cols, axis=1)                 # (n_pad, kw)
 
 
@@ -114,7 +114,7 @@ def _boundary_inputs(macro64, Wu: int, space: int, lane, n_pad: int, TB: int,
     space_m1 = U.u64_from_int(space - 1, like=lane)
     gb64 = U.u64_add_u32(macro64, np.uint32(Wu))
     jb = U.u64_ctz(gb64)
-    sb = 2 * U.u64_bit(U.u64_gray(gb64), jb).astype(dtype) - 1
+    sb = 2 * U.to_float(U.u64_bit(U.u64_gray(gb64), jb), dtype) - 1
     live = U.u64_leq(gb64, space_m1).astype(dtype)
     row_iota = jax.lax.broadcasted_iota(jnp.uint32, (n_pad, TB), 0)
     onehot = (row_iota == jb[None, :].astype(jnp.uint32)).astype(dtype)
@@ -137,7 +137,7 @@ def _ryser_block_sp(i, A, rows, vals, xb, c0, dev_base, *, n: int,
 
     start64, lane = _chunk_starts(i, dev_base, TB, C)
     Gb = _gray_init_bits(start64, n, n_pad, TB, dtype)
-    X = xb + jax.lax.dot_general(A, Gb, dd, preferred_element_type=dtype)
+    X = xb + _mm(A, Gb, dtype)
 
     sched = _signed_const_schedule(Wu)
     mid_idx = next((ix for ix, st in enumerate(sched) if st[2]), None)
@@ -146,21 +146,20 @@ def _ryser_block_sp(i, A, rows, vals, xb, c0, dev_base, *, n: int,
     # window states from the scattered low columns -- macro-invariant:
     # the inner schedule flips columns 0..kw-1 in every window
     Ucols = _scatter_low_columns(rows, vals, kw, n_pad, dtype)
-    D = jax.lax.dot_general(Ucols, c0[:kw, :], dd,
-                            preferred_element_type=dtype)  # (n_pad, Wu-1)
+    D = _mm(Ucols, c0[:kw, :], dtype)  # (n_pad, Wu-1)
     col_mid = Ucols[:, kw - 1:kw]
 
     def macro_body(m, carry):
         X, acc = carry
         macro64 = U.u64_add_u32(start64,
                                 m.astype(jnp.uint32) * np.uint32(Wu))
-        bitk = U.u64_bit(macro64, np.uint32(kw)).astype(dtype)
+        bitk = U.to_float(U.u64_bit(macro64, np.uint32(kw)), dtype)
         corr = col_mid * (float(-2.0 * s_mid) * bitk)[None, :]
         for idx, (j, s, is_mid, parity) in enumerate(sched):
             state = X + D[:, idx][:, None]
             if mid_idx is not None and idx >= mid_idx:
                 state = state + corr
-            prod = jnp.prod(state, axis=0)  # permlint: disable=PL001  # fixed-axis lane product inside one block
+            prod = _rprod(state)
             acc = _accum_add(acc, -prod if parity else prod, precision)
         X = X + D[:, Wu - 2][:, None] if Wu >= 2 else X
         if mid_idx is not None:
@@ -170,9 +169,9 @@ def _ryser_block_sp(i, A, rows, vals, xb, c0, dev_base, *, n: int,
         # is resident for the init matmul anyway -- same as jnp SpaRyser)
         onehot, sgn, live = _boundary_inputs(macro64, Wu, space, lane,
                                              n_pad, TB, dtype)
-        colb = jax.lax.dot_general(A, onehot, dd, preferred_element_type=dtype)
+        colb = _mm(A, onehot, dtype)
         X = X + colb * sgn[None, :]
-        prod = jnp.prod(X, axis=0)  # permlint: disable=PL001  # fixed-axis lane product inside one block
+        prod = _rprod(X)
         acc = _accum_add(acc, prod * live, precision)  # (-1)^Wu == +1
         return (X, acc)
 
@@ -180,7 +179,8 @@ def _ryser_block_sp(i, A, rows, vals, xb, c0, dev_base, *, n: int,
     if M == 1:
         X, acc = macro_body(jnp.int32(0), (X, acc0))
     else:
-        X, acc = jax.lax.fori_loop(0, M, macro_body, (X, acc0))
+        X, acc = jax.lax.fori_loop(jnp.int32(0), jnp.int32(M), macro_body,
+                                   (X, acc0))
 
     hi, lo = _accum_value(acc, precision)
     # permlint: disable=PL001  # in-kernel lane reduce, under the 1e-9 kernel contract
@@ -200,8 +200,8 @@ def _ryser_block_sp_cx(i, Ar, Ai, rows, vals_r, vals_i, xbr, xbi, c0,
 
     start64, lane = _chunk_starts(i, dev_base, TB, C)
     Gb = _gray_init_bits(start64, n, n_pad, TB, dtype)
-    Xr = xbr + jax.lax.dot_general(Ar, Gb, dd, preferred_element_type=dtype)
-    Xi = xbi + jax.lax.dot_general(Ai, Gb, dd, preferred_element_type=dtype)
+    Xr = xbr + _mm(Ar, Gb, dtype)
+    Xi = xbi + _mm(Ai, Gb, dtype)
 
     sched = _signed_const_schedule(Wu)
     mid_idx = next((ix for ix, st in enumerate(sched) if st[2]), None)
@@ -209,8 +209,8 @@ def _ryser_block_sp_cx(i, Ar, Ai, rows, vals_r, vals_i, xbr, xbi, c0,
 
     Ur = _scatter_low_columns(rows, vals_r, kw, n_pad, dtype)
     Ui = _scatter_low_columns(rows, vals_i, kw, n_pad, dtype)
-    Dr = jax.lax.dot_general(Ur, c0[:kw, :], dd, preferred_element_type=dtype)
-    Di = jax.lax.dot_general(Ui, c0[:kw, :], dd, preferred_element_type=dtype)
+    Dr = _mm(Ur, c0[:kw, :], dtype)
+    Di = _mm(Ui, c0[:kw, :], dtype)
     cmr = Ur[:, kw - 1:kw]
     cmi = Ui[:, kw - 1:kw]
 
@@ -218,7 +218,7 @@ def _ryser_block_sp_cx(i, Ar, Ai, rows, vals_r, vals_i, xbr, xbi, c0,
         Xr, Xi, acc_r, acc_i = carry
         macro64 = U.u64_add_u32(start64,
                                 m.astype(jnp.uint32) * np.uint32(Wu))
-        bitk = U.u64_bit(macro64, np.uint32(kw)).astype(dtype)
+        bitk = U.to_float(U.u64_bit(macro64, np.uint32(kw)), dtype)
         corr = (float(-2.0 * s_mid) * bitk)[None, :]
         for idx, (j, s, is_mid, parity) in enumerate(sched):
             sr = Xr + Dr[:, idx][:, None]
@@ -238,10 +238,8 @@ def _ryser_block_sp_cx(i, Ar, Ai, rows, vals_r, vals_i, xbr, xbi, c0,
         # boundary step (dense one-hot MXU, both planes)
         onehot, sgn, live = _boundary_inputs(macro64, Wu, space, lane,
                                              n_pad, TB, dtype)
-        colr = jax.lax.dot_general(Ar, onehot, dd,
-                                   preferred_element_type=dtype)
-        coli = jax.lax.dot_general(Ai, onehot, dd,
-                                   preferred_element_type=dtype)
+        colr = _mm(Ar, onehot, dtype)
+        coli = _mm(Ai, onehot, dtype)
         Xr = Xr + colr * sgn[None, :]
         Xi = Xi + coli * sgn[None, :]
         pr, pi = _cprod(Xr, Xi, n_pad)
@@ -256,7 +254,7 @@ def _ryser_block_sp_cx(i, Ar, Ai, rows, vals_r, vals_i, xbr, xbi, c0,
                                           (Xr, Xi, acc_r, acc_i))
     else:
         Xr, Xi, acc_r, acc_i = jax.lax.fori_loop(
-            0, M, macro_body, (Xr, Xi, acc_r, acc_i))
+            jnp.int32(0), jnp.int32(M), macro_body, (Xr, Xi, acc_r, acc_i))
 
     zero = jnp.zeros((), dtype)
     keep_err = precision in ("dq_acc", "dq_fast")
@@ -271,14 +269,12 @@ def _ryser_block_sp_cx(i, Ar, Ai, rows, vals_r, vals_i, xbr, xbi, c0,
 
 def _ryser_sp_kernel(base_hi_ref, base_lo_ref, A_ref, rows_ref, vals_ref,
                      xb_ref, c0_ref, out_ref, **geom):
-    """Single-matrix kernel: grid = (num_blocks,); writes (1, 2) partials."""
+    """Single-matrix kernel: grid = (num_blocks,); writes (hi, lo)."""
     dev = (base_hi_ref[0, 0].astype(jnp.uint32),
            base_lo_ref[0, 0].astype(jnp.uint32))
-    hi, lo = _ryser_block_sp(pl.program_id(0), A_ref[...], rows_ref[...],
-                             vals_ref[...], xb_ref[...], c0_ref[...], dev,
-                             **geom)
-    out_ref[0, 0] = hi
-    out_ref[0, 1] = lo
+    _write_partials(out_ref, _ryser_block_sp(
+        pl.program_id(0), A_ref[...], rows_ref[...], vals_ref[...],
+        xb_ref[...], c0_ref[...], dev, **geom))
 
 
 def _ryser_sp_kernel_batched(A_ref, rows_ref, vals_ref, xb_ref, c0_ref,
@@ -287,41 +283,31 @@ def _ryser_sp_kernel_batched(A_ref, rows_ref, vals_ref, xb_ref, c0_ref,
     whole bucket.  Block b of the stacks is selected by the BlockSpec;
     the chunk base is 0 (each matrix owns its full iteration space)."""
     zero = jnp.uint32(0)
-    hi, lo = _ryser_block_sp(pl.program_id(1), A_ref[0], rows_ref[0],
-                             vals_ref[0], xb_ref[0], c0_ref[...],
-                             (zero, zero), **geom)
-    out_ref[0, 0, 0] = hi
-    out_ref[0, 0, 1] = lo
+    _write_partials(out_ref, _ryser_block_sp(
+        pl.program_id(1), A_ref[0], rows_ref[0], vals_ref[0], xb_ref[0],
+        c0_ref[...], (zero, zero), **geom))
 
 
 def _ryser_sp_kernel_cx(base_hi_ref, base_lo_ref, Ar_ref, Ai_ref, rows_ref,
                         vr_ref, vi_ref, xbr_ref, xbi_ref, c0_ref, out_ref,
                         **geom):
-    """Single-matrix complex kernel: grid = (num_blocks,); (1, 4) partials."""
+    """Single-matrix complex kernel: grid = (num_blocks,); 4 partials."""
     dev = (base_hi_ref[0, 0].astype(jnp.uint32),
            base_lo_ref[0, 0].astype(jnp.uint32))
-    hr, er, hi, ei = _ryser_block_sp_cx(
+    _write_partials(out_ref, _ryser_block_sp_cx(
         pl.program_id(0), Ar_ref[...], Ai_ref[...], rows_ref[...],
         vr_ref[...], vi_ref[...], xbr_ref[...], xbi_ref[...], c0_ref[...],
-        dev, **geom)
-    out_ref[0, 0] = hr
-    out_ref[0, 1] = er
-    out_ref[0, 2] = hi
-    out_ref[0, 3] = ei
+        dev, **geom))
 
 
 def _ryser_sp_kernel_cx_batched(Ar_ref, Ai_ref, rows_ref, vr_ref, vi_ref,
                                 xbr_ref, xbi_ref, c0_ref, out_ref, **geom):
-    """Batch-grid complex kernel: grid = (B, num_blocks); (1, 1, 4)."""
+    """Batch-grid complex kernel: grid = (B, num_blocks); 4 partials."""
     zero = jnp.uint32(0)
-    hr, er, hi, ei = _ryser_block_sp_cx(
+    _write_partials(out_ref, _ryser_block_sp_cx(
         pl.program_id(1), Ar_ref[0], Ai_ref[0], rows_ref[0], vr_ref[0],
         vi_ref[0], xbr_ref[0], xbi_ref[0], c0_ref[...], (zero, zero),
-        **geom)
-    out_ref[0, 0, 0] = hr
-    out_ref[0, 0, 1] = er
-    out_ref[0, 0, 2] = hi
-    out_ref[0, 0, 3] = ei
+        **geom))
 
 
 def _c0_input(Wu: int, n_pad: int, dtype):
@@ -331,13 +317,14 @@ def _c0_input(Wu: int, n_pad: int, dtype):
 def ryser_sparse_pallas_call(A_pad, rows, vals, xb, dev_chunk_base, *,
                              n: int, TB: int, C: int, Wu: int,
                              num_blocks: int, precision: str = "dq_acc",
-                             interpret: bool = True, vma=None):
+                             interpret: bool | None = None, vma=None):
     """(num_blocks, 2) sparse (hi, lo) partials, base g=0 term NOT included.
 
     ``rows``/``vals`` are the (n, maxdeg) padded CCS arrays of ONE matrix;
     ``dev_chunk_base`` may be a host int or a traced scalar (the
     distributed shard_map path), exactly like the dense kernels.
     """
+    interpret = pallas_interpret(A_pad, vals, xb, interpret=interpret)
     n_pad = A_pad.shape[0]
     dtype = A_pad.dtype
     maxdeg = rows.shape[-1]
@@ -346,7 +333,8 @@ def ryser_sparse_pallas_call(A_pad, rows, vals, xb, dev_chunk_base, *,
     kernel = functools.partial(
         _ryser_sp_kernel, n=n, n_pad=n_pad, TB=TB, C=C, Wu=Wu,
         space=1 << (n - 1), precision=precision, dtype=dtype)
-    rep = lambda i: (0, 0)
+    rep = lambda i: (_Z, _Z)
+    out_spec, out_shape = _partials_out(num_blocks, dtype, vma=vma)
     return pl.pallas_call(
         kernel,
         grid=(num_blocks,),
@@ -358,21 +346,23 @@ def ryser_sparse_pallas_call(A_pad, rows, vals, xb, dev_chunk_base, *,
             pl.BlockSpec((n_pad, 1), rep),
             pl.BlockSpec(c0.shape, rep),
         ],
-        out_specs=pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        out_shape=shape_dtype_struct((num_blocks, 2), dtype, vma=vma),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(base_hi, base_lo, A_pad, rows, vals, xb, c0)
+    )(base_hi, base_lo, A_pad, rows, vals, xb, c0)[:, 0, :2]
 
 
 def ryser_sparse_pallas_call_batched(A_pads, rows_stack, vals_stack,
                                      xb_pads, *, n: int, TB: int, C: int,
                                      Wu: int, num_blocks: int,
                                      precision: str = "dq_acc",
-                                     interpret: bool = True):
+                                     interpret: bool | None = None):
     """Launch ONE sparse kernel over a (B, n_pad, n_pad) + (B, n, maxdeg)
     padded-CCS bucket: grid is (batch, block), the sparse analogue of
     ``ryser_pallas_call_batched`` (same geometry inputs and window
     schedule).  Returns (B, num_blocks, 2) (hi, lo) partials."""
+    interpret = pallas_interpret(A_pads, vals_stack, xb_pads,
+                                 interpret=interpret)
     B, n_pad, _ = A_pads.shape
     dtype = A_pads.dtype
     maxdeg = rows_stack.shape[-1]
@@ -380,7 +370,8 @@ def ryser_sparse_pallas_call_batched(A_pads, rows_stack, vals_stack,
     kernel = functools.partial(
         _ryser_sp_kernel_batched, n=n, n_pad=n_pad, TB=TB, C=C, Wu=Wu,
         space=1 << (n - 1), precision=precision, dtype=dtype)
-    sel = lambda b, i: (b, 0, 0)
+    sel = lambda b, i: (b, _Z, _Z)
+    out_spec, out_shape = _partials_out(num_blocks, dtype, batch=B)
     return pl.pallas_call(
         kernel,
         grid=(B, num_blocks),
@@ -389,12 +380,12 @@ def ryser_sparse_pallas_call_batched(A_pads, rows_stack, vals_stack,
             pl.BlockSpec((1, n, maxdeg), sel),
             pl.BlockSpec((1, n, maxdeg), sel),
             pl.BlockSpec((1, n_pad, 1), sel),
-            pl.BlockSpec(c0.shape, lambda b, i: (0, 0)),
+            pl.BlockSpec(c0.shape, lambda b, i: (_Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 2), lambda b, i: (b, i, 0)),
-        out_shape=shape_dtype_struct((B, num_blocks, 2), dtype),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(A_pads, rows_stack, vals_stack, xb_pads, c0)
+    )(A_pads, rows_stack, vals_stack, xb_pads, c0)[:, :, 0, :2]
 
 
 def ryser_sparse_pallas_call_complex(Ar_pad, Ai_pad, rows, vals_r, vals_i,
@@ -402,9 +393,12 @@ def ryser_sparse_pallas_call_complex(Ar_pad, Ai_pad, rows, vals_r, vals_i,
                                      TB: int, C: int, Wu: int,
                                      num_blocks: int,
                                      precision: str = "dq_acc",
-                                     interpret: bool = True, vma=None):
+                                     interpret: bool | None = None,
+                                     vma=None):
     """(num_blocks, 4) split-plane sparse partials
     (re_hi, re_err, im_hi, im_err); chunk base host int or traced."""
+    interpret = pallas_interpret(Ar_pad, Ai_pad, vals_r, vals_i, xbr, xbi,
+                                 interpret=interpret)
     n_pad = Ar_pad.shape[0]
     dtype = Ar_pad.dtype
     maxdeg = rows.shape[-1]
@@ -413,7 +407,8 @@ def ryser_sparse_pallas_call_complex(Ar_pad, Ai_pad, rows, vals_r, vals_i,
     kernel = functools.partial(
         _ryser_sp_kernel_cx, n=n, n_pad=n_pad, TB=TB, C=C, Wu=Wu,
         space=1 << (n - 1), precision=precision, dtype=dtype)
-    rep = lambda i: (0, 0)
+    rep = lambda i: (_Z, _Z)
+    out_spec, out_shape = _partials_out(num_blocks, dtype, vma=vma)
     return pl.pallas_call(
         kernel,
         grid=(num_blocks,),
@@ -427,10 +422,11 @@ def ryser_sparse_pallas_call_complex(Ar_pad, Ai_pad, rows, vals_r, vals_i,
             pl.BlockSpec((n_pad, 1), rep), pl.BlockSpec((n_pad, 1), rep),
             pl.BlockSpec(c0.shape, rep),
         ],
-        out_specs=pl.BlockSpec((1, 4), lambda i: (i, 0)),
-        out_shape=shape_dtype_struct((num_blocks, 4), dtype, vma=vma),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(base_hi, base_lo, Ar_pad, Ai_pad, rows, vals_r, vals_i, xbr, xbi, c0)
+    )(base_hi, base_lo, Ar_pad, Ai_pad, rows, vals_r, vals_i, xbr, xbi,
+      c0)[:, 0, :4]
 
 
 def ryser_sparse_pallas_call_complex_batched(Ar_pads, Ai_pads, rows_stack,
@@ -439,9 +435,12 @@ def ryser_sparse_pallas_call_complex_batched(Ar_pads, Ai_pads, rows_stack,
                                              TB: int, C: int, Wu: int,
                                              num_blocks: int,
                                              precision: str = "dq_acc",
-                                             interpret: bool = True):
+                                             interpret: bool | None = None):
     """(B, num_blocks, 4) split-plane sparse partials over a (batch, block)
     grid -- the complex analogue of ``ryser_sparse_pallas_call_batched``."""
+    interpret = pallas_interpret(Ar_pads, Ai_pads, vals_r_stack,
+                                 vals_i_stack, xbr_pads, xbi_pads,
+                                 interpret=interpret)
     B, n_pad, _ = Ar_pads.shape
     dtype = Ar_pads.dtype
     maxdeg = rows_stack.shape[-1]
@@ -449,7 +448,8 @@ def ryser_sparse_pallas_call_complex_batched(Ar_pads, Ai_pads, rows_stack,
     kernel = functools.partial(
         _ryser_sp_kernel_cx_batched, n=n, n_pad=n_pad, TB=TB, C=C, Wu=Wu,
         space=1 << (n - 1), precision=precision, dtype=dtype)
-    sel = lambda b, i: (b, 0, 0)
+    sel = lambda b, i: (b, _Z, _Z)
+    out_spec, out_shape = _partials_out(num_blocks, dtype, batch=B)
     return pl.pallas_call(
         kernel,
         grid=(B, num_blocks),
@@ -461,10 +461,10 @@ def ryser_sparse_pallas_call_complex_batched(Ar_pads, Ai_pads, rows_stack,
             pl.BlockSpec((1, n, maxdeg), sel),
             pl.BlockSpec((1, n_pad, 1), sel),
             pl.BlockSpec((1, n_pad, 1), sel),
-            pl.BlockSpec(c0.shape, lambda b, i: (0, 0)),
+            pl.BlockSpec(c0.shape, lambda b, i: (_Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 4), lambda b, i: (b, i, 0)),
-        out_shape=shape_dtype_struct((B, num_blocks, 4), dtype),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
     )(Ar_pads, Ai_pads, rows_stack, vals_r_stack, vals_i_stack,
-      xbr_pads, xbi_pads, c0)
+      xbr_pads, xbi_pads, c0)[:, :, 0, :4]

@@ -21,7 +21,7 @@ import numpy as np
 __all__ = [
     "u64", "u64_from_int", "u64_add", "u64_add_u32", "u64_shl",
     "u64_shr1", "u64_xor", "u64_gray", "u64_bit", "u64_ctz", "u64_leq",
-    "ctz32",
+    "ctz32", "to_float",
 ]
 
 U1 = np.uint32(1)
@@ -88,11 +88,21 @@ def u64_bit(a, j):
     """Bit j (0..63, traced per-lane uint32 array) as uint32 {0, 1}."""
     hi, lo = a
     j = jnp.asarray(j, jnp.uint32)
-    jlo = jnp.minimum(j, np.uint32(31))
-    jhi = jnp.minimum(j - np.uint32(32), np.uint32(31))
+    # where/compare, not jnp.minimum: Mosaic cannot legalize unsigned min
+    c31 = np.uint32(31)
+    jlo = jnp.where(j < c31, j, c31)
+    jhi = jnp.where(j - np.uint32(32) < c31, j - np.uint32(32), c31)
     from_lo = (lo >> jlo) & U1
     from_hi = (hi >> jhi) & U1
     return jnp.where(j < np.uint32(32), from_lo, from_hi)
+
+
+def to_float(v, dtype):
+    """A uint32 below 2^31 (a bit, an index) as float ``dtype``.
+
+    Goes through int32: Mosaic has no uint32 -> float conversion.
+    """
+    return v.astype(jnp.int32).astype(dtype)
 
 
 def ctz32(v):
@@ -100,13 +110,15 @@ def ctz32(v):
 
     v & -v isolates the lowest set bit (a power of two <= 2^31); its f32
     representation is exact, so the unbiased exponent equals the index.
+    The conversion goes through int32 (Mosaic has no uint32 -> float),
+    which turns 2^31 into -2^31: same exponent, sign bit masked off.
     Avoids relying on popcount support in the TPU vector ISA.
     """
     import jax
     low = v & (~v + U1)
-    f = low.astype(jnp.float32)
+    f = low.astype(jnp.int32).astype(jnp.float32)
     bits = jax.lax.bitcast_convert_type(f, jnp.uint32)
-    exp = (bits >> np.uint32(23)).astype(jnp.int32) - 127
+    exp = ((bits >> np.uint32(23)) & np.uint32(0xFF)).astype(jnp.int32) - 127
     return exp.astype(jnp.uint32)
 
 

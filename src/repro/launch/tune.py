@@ -100,7 +100,8 @@ def tune_main(argv=None) -> int:
     table, report = tune_table(
         routes, ns, density=args.density, precision=args.precision,
         batch=args.batch, top_k=args.top_k, repeats=args.repeats,
-        interpret=args.interpret, seed=args.seed, mesh=mesh,
+        interpret=True if args.interpret else None, seed=args.seed,
+        mesh=mesh,
         progress=progress)
     table.save(args.out)
     print(f"[tune] {len(table.entries)} entr(ies) -> {args.out} "

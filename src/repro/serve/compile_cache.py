@@ -5,11 +5,12 @@ geometry it meets traces and XLA-compiles before the first result comes
 back.  Two layers fix that:
 
 * :func:`enable_compile_cache` points ``jax``'s persistent compilation
-  cache (``jax.config`` ``jax_compilation_cache_dir`` wiring, thresholds
-  zeroed so every executable persists) at an on-disk directory keyed the
-  same way ``core/cache.py`` keys results -- by content, here the HLO +
-  compile options, so identical programs across process restarts load
-  their executable from disk instead of re-invoking XLA.
+  cache (thresholds zeroed so every executable persists) at an on-disk
+  directory -- ``JAX_COMPILATION_CACHE_DIR`` when the environment sets
+  it, else a fixed directory in the checkout -- keyed the same way
+  ``core/cache.py`` keys results: by content, here the HLO + compile
+  options, so identical programs across process restarts load their
+  executable from disk instead of re-invoking XLA.
 * :func:`warmup` runs the serve plan's kernel geometries -- every
   (n, device-batch, dtype) bucket program the loop can dispatch -- through
   a throwaway solver before traffic is admitted.  Tracing happens once,
@@ -37,7 +38,8 @@ from typing import Sequence
 
 import numpy as np
 
-__all__ = ["enable_compile_cache", "install_compile_listener",
+__all__ = ["DEFAULT_CACHE_DIR", "enable_compile_cache",
+           "install_compile_listener",
            "compile_stats", "reset_compile_stats", "warmup",
            "quantized_batches"]
 
@@ -85,13 +87,32 @@ def reset_compile_stats() -> None:
         _counts[k] = 0
 
 
-def enable_compile_cache(path: str) -> str:
-    """Wire jax's persistent compilation cache at ``path`` (created if
-    missing) and start counting cache events.  Returns the path."""
+# The default cache directory: fixed (a cache that moves never hits) and
+# inside the checkout (listed in .gitignore), so nothing is read or
+# written outside the tree.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_compile_cache(path: str | None = None) -> str:
+    """Turn on jax's persistent compilation cache and start counting
+    cache events.  Returns the cache directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory: jax reads
+    the variable itself, and no other directory is configured in its
+    place (``path`` is ignored).  Otherwise the cache lives at ``path``,
+    or by default at :data:`DEFAULT_CACHE_DIR`.
+    """
     import jax
-    path = os.path.abspath(os.path.expanduser(path))
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        path = os.path.abspath(os.path.expanduser(env))
+        os.makedirs(path, exist_ok=True)
+    else:
+        path = os.path.abspath(os.path.expanduser(path or DEFAULT_CACHE_DIR))
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     # persist everything: the bucket programs this service compiles are
     # small and hot, and the default thresholds would skip exactly the
     # tiny-n programs the retrace storm is made of

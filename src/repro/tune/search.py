@@ -20,9 +20,9 @@ Pipeline per ``(route, n, density_bucket, dtype, precision)`` key:
    ``benchmarks/roofline_report.py``.
 4. **Persist** the winner as a :class:`~repro.tune.table.TableEntry`.
 
-Everything here runs in interpret mode on CPU (``--interpret``) or
-compiled on a real accelerator; the table records which via
-``device_kind``.
+Kernels run in interpret mode on CPU and compiled on an accelerator
+(``kernels.ops.pallas_interpret`` decides from the platform); the table
+records which via ``device_kind``.
 """
 
 from __future__ import annotations
@@ -148,7 +148,7 @@ def _median_time(call, args, repeats: int) -> float:
 
 
 def _route_callable(route: str, n: int, *, density: float, batch: int,
-                    precision: str, interpret: bool, seed: int,
+                    precision: str, interpret: bool | None, seed: int,
                     mesh=None):
     """(jitted fn, concrete args) measuring one launch of ``route``.
 
@@ -236,7 +236,7 @@ def measure_candidate(call_factory, geometry: Geometry, *, repeats: int,
 def tune_key(route: str, n: int, *, density: float = 1.0,
              dtype: str = "<f8", precision: str = "dq_acc",
              batch: int = 16, top_k: int = 3, repeats: int = 3,
-             interpret: bool = True, seed: int = 0, mesh=None,
+             interpret: bool | None = None, seed: int = 0, mesh=None,
              hw: HwSpec | None = None):
     """Tune one table key; returns (TableEntry, candidate report rows).
 
@@ -292,7 +292,7 @@ def tune_key(route: str, n: int, *, density: float = 1.0,
 
 def tune_table(routes, ns, *, density: float = 1.0,
                precision: str = "dq_acc", batch: int = 16, top_k: int = 3,
-               repeats: int = 3, interpret: bool = True, seed: int = 0,
+               repeats: int = 3, interpret: bool | None = None, seed: int = 0,
                mesh=None, table: TuningTable | None = None,
                progress=None):
     """Tune every (route, n) pair into a TuningTable.

@@ -1,67 +1,21 @@
-"""JAX cross-version compatibility shims.
-
-The repo targets the modern JAX API surface (``jax.shard_map``, varying
-manual axes on ``ShapeDtypeStruct``) and resolves the native symbols ONCE
-at import time, never per call.  The dual-path signature-sniffing layer
-that used to probe ``check_vma``/``check_rep`` under every module path is
-gone (ROADMAP upstream-facing item): resolution is a single two-way
-branch -- ``jax.shard_map`` when it exists (one signature probe picks the
-check kwarg: releases that promoted the symbol before the
-``check_rep -> check_vma`` rename still take the old name), else
-``jax.experimental.shard_map`` with its ``check_rep`` kwarg (same
-semantics: disable the per-output replication/vma typing check), covering
-the still-supported 0.4.x line.  That fallback CANNOT be dropped yet: the
-CI floor pins ``jax>=0.4.30,<0.5``, and no 0.4.x release ever shipped the
-native symbol -- delete the ``else`` branch (and this paragraph) when the
-floor moves to a JAX with ``jax.shard_map``.
-
-Exports:
+"""The two JAX entry points the mesh programs and kernels share.
 
 * ``shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=...)``
-  -- version-portable shard_map mirroring the modern keyword API.
-* ``shape_dtype_struct(shape, dtype, vma=None)`` -- ``ShapeDtypeStruct``
-  that forwards ``vma`` (varying manual axes) only on JAX versions whose
-  constructor accepts it; older versions simply don't track vma, which is
-  equivalent to running with ``check_vma=False``.
-* ``HAS_NATIVE_SHARD_MAP`` -- True when ``jax.shard_map`` exists.
+  -- ``jax.shard_map``.
+* ``shape_dtype_struct(shape, dtype, vma=None)`` -- a
+  ``ShapeDtypeStruct`` carrying the varying manual axes of a kernel
+  output launched under ``shard_map``.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 
-__all__ = ["shard_map", "shape_dtype_struct", "HAS_NATIVE_SHARD_MAP"]
+__all__ = ["shard_map", "shape_dtype_struct"]
 
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-
-if HAS_NATIVE_SHARD_MAP:
-    _SHARD_MAP_IMPL = jax.shard_map
-    # the symbol went top-level before the check_rep -> check_vma rename:
-    # probe the native signature once rather than assume the modern name
-    try:
-        _CHECK_KW = "check_vma" if "check_vma" in inspect.signature(
-            _SHARD_MAP_IMPL).parameters else "check_rep"
-    except (TypeError, ValueError):  # unsignaturable wrapper: modern kwarg
-        _CHECK_KW = "check_vma"
-else:  # JAX 0.4.x (the CI floor): pre-vma-typing era, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _SHARD_MAP_IMPL
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map`` mirroring the modern keyword API;
-    ``check_vma`` travels as ``check_rep`` on the 0.4.x fallback."""
-    return _SHARD_MAP_IMPL(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KW: check_vma})
+shard_map = jax.shard_map
 
 
 def shape_dtype_struct(shape, dtype, vma=None):
-    """``jax.ShapeDtypeStruct`` forwarding ``vma`` only where supported."""
-    if vma is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:  # JAX 0.4.x: no vma typing on avals
-        return jax.ShapeDtypeStruct(shape, dtype)
+    """``jax.ShapeDtypeStruct`` with optional varying manual axes."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

@@ -12,13 +12,14 @@ Hardware is resolved by name through :data:`HW_SPECS`:
 :func:`detect_hw` maps ``jax.devices()[0].device_kind`` onto a registered
 spec (explicitly overridable via its argument or the ``REPRO_HW``
 environment variable), so the tuner's pruning model and the roofline
-report stop assuming v5e.  Unknown kinds fall back to ``tpu-v5e``.
+report stop assuming v5e.  An unknown kind or name raises: no code path
+picks a device spec by default.
 """
 
 from __future__ import annotations
 
 import os
-from dataclasses import dataclass, asdict
+from dataclasses import dataclass, asdict, field
 
 __all__ = ["HwSpec", "HW_SPECS", "HW_V5E", "detect_hw", "get_hw",
            "register_hw", "Roofline", "roofline_from_analysis"]
@@ -50,8 +51,6 @@ HW_SPECS: dict[str, HwSpec] = {
     "cpu": HwSpec(name="cpu", peak_flops=100e9, hbm_bw=20e9, ici_bw=10e9),
 }
 
-_DEFAULT_HW = "tpu-v5e"
-
 # device_kind substrings -> registry keys, checked in order (the kind
 # strings vary across jax versions: "TPU v5e", "TPU v5 lite", ...).
 _KIND_PATTERNS = (
@@ -65,9 +64,13 @@ def register_hw(spec: HwSpec) -> None:
     HW_SPECS[spec.name] = spec
 
 
-def get_hw(name: str | None = None) -> HwSpec:
-    """Spec by registry name; None/unknown falls back to the default."""
-    return HW_SPECS.get(name or _DEFAULT_HW, HW_V5E)
+def get_hw(name: str) -> HwSpec:
+    """Spec by registry name; an unknown name raises ``KeyError``."""
+    try:
+        return HW_SPECS[name]
+    except KeyError:
+        raise KeyError(f"no hardware spec named {name!r}; registered: "
+                       f"{sorted(HW_SPECS)}") from None
 
 
 def detect_hw(device_kind: str | None = None) -> HwSpec:
@@ -75,26 +78,25 @@ def detect_hw(device_kind: str | None = None) -> HwSpec:
 
     Precedence: explicit ``device_kind`` argument > ``REPRO_HW``
     environment override (a registry name) > ``jax.devices()[0]``
-    autodetection > the v5e default.  jax is imported lazily and any
-    failure degrades to the default -- callers never see an exception.
+    autodetection.  A kind no pattern matches raises ``ValueError``
+    (register its spec with :func:`register_hw`): a roofline against
+    another chip's peaks is wrong, not approximate.
     """
     override = os.environ.get("REPRO_HW")
     if device_kind is None and override:
         return get_hw(override)
     kind = device_kind
     if kind is None:
-        try:
-            import jax
-            kind = jax.devices()[0].device_kind
-        except Exception:  # noqa: BLE001 -- detection must never raise
-            return get_hw(None)
+        import jax
+        kind = jax.devices()[0].device_kind
     low = str(kind).lower()
     if low in HW_SPECS:
         return HW_SPECS[low]
     for pat, name in _KIND_PATTERNS:
         if pat in low:
             return HW_SPECS[name]
-    return get_hw(None)
+    raise ValueError(f"no hardware spec for device kind {kind!r}; "
+                     f"register one with register_hw()")
 
 
 @dataclass
@@ -106,7 +108,7 @@ class Roofline:
     model_flops: float = 0.0   # 6 N D (dense) / 6 N_active D (MoE)
     bytes_min: float = 0.0     # per-device argument+output traffic
                                # (fusion-optimal lower bound)
-    hw: str = _DEFAULT_HW
+    hw: str = field(kw_only=True)   # HW_SPECS name
 
     @property
     def spec(self) -> HwSpec:

@@ -148,7 +148,8 @@ def test_count_ops_merges_async_pairs_and_sees_fusion_bodies():
 def test_roofline_terms_and_dominant():
     rl = Roofline(flops=197e12 * 256, bytes_accessed=0.0,
                   collective_bytes=100e9, chips=256,
-                  model_flops=100e12 * 256, bytes_min=819e9)
+                  model_flops=100e12 * 256, bytes_min=819e9,
+                  hw="tpu-v5e")
     assert abs(rl.compute_s - 1.0) < 1e-9
     assert abs(rl.memory_s - 1.0) < 1e-9
     assert abs(rl.collective_s - 2.0) < 1e-9

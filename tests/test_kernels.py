@@ -141,3 +141,54 @@ def test_complex_unitary_submatrix_probability():
     want = oracle.perm_ryser_exact(sub)
     assert abs(amp - want) / abs(want) < 1e-10
     assert 0 <= abs(amp) ** 2 <= 1 + 1e-9
+
+
+# ---------------------------------------------------------------------------
+# interpret mode follows the platform; no 64-bit kernel off the CPU
+# ---------------------------------------------------------------------------
+
+def _on_platform(monkeypatch, platform):
+    """Make the kernels see ``platform`` as this process's backend."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+
+
+@pytest.mark.parametrize("platform,want", [("cpu", True), ("tpu", False)])
+def test_interpret_mode_follows_platform(monkeypatch, platform, want):
+    _on_platform(monkeypatch, platform)
+    x = jnp.zeros((8, 8), jnp.float32)
+    assert ops.pallas_interpret(x) is want
+    assert ops.pallas_interpret(x, interpret=False) is False
+
+
+def test_interpret_mode_is_refused_off_the_cpu(monkeypatch):
+    _on_platform(monkeypatch, "tpu")
+    with pytest.raises(ValueError, match="CPU only"):
+        ops.pallas_interpret(jnp.zeros((8, 8), jnp.float32), interpret=True)
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.complex128])
+def test_64bit_kernel_off_the_cpu_raises(monkeypatch, dtype):
+    """backend='pallas' on a chip: a clear error naming the f32-base
+    item, never the interpreter and never a silent jnp fallback."""
+    from repro.core.solver import PermanentSolver, SolverConfig
+    _on_platform(monkeypatch, "tpu")
+    A = RNG.uniform(-1, 1, (7, 7)).astype(dtype)
+    with pytest.raises(TypeError, match="f32-base numerics"):
+        ops.permanent_pallas(A)
+    solver = PermanentSolver(SolverConfig(backend="pallas", cache=False))
+    with pytest.raises(TypeError, match="f32-base numerics"):
+        solver.execute(solver.plan(A))
+
+
+def test_64bit_campaign_pallas_wave_off_the_cpu_raises(monkeypatch):
+    import jax
+    from jax.sharding import Mesh
+    from repro.core import distributed as Dm
+    _on_platform(monkeypatch, "tpu")
+    mesh = Mesh(np.array(jax.devices()[:1]), ("step",))
+    A = RNG.uniform(-1, 1, (9, 9))
+    with pytest.raises(TypeError, match="f32-base numerics"):
+        Dm.slice_sums_on_mesh(A, mesh, np.array([0], np.int32),
+                              chunks_per_slice=4, chunk_size=16,
+                              backend="pallas")

@@ -403,6 +403,7 @@ def test_warm_compile_cache_cold_start(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = src + os.pathsep * bool(env.get("PYTHONPATH")) \
         + env.get("PYTHONPATH", "")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)   # the test needs its own
 
     def cold_run():
         r = subprocess.run(
@@ -419,6 +420,39 @@ def test_warm_compile_cache_cold_start(tmp_path):
     assert int(run2["warm_hits"]) > 0
     assert int(run1["first_misses"]) == 0        # warm-up covered the
     assert int(run2["first_misses"]) == 0        # first bucket's geometry
+
+
+@pytest.mark.parametrize("env_dir", [False, True], ids=["default", "env"])
+def test_enable_compile_cache_directory(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and no other
+    directory is configured; otherwise the cache sits at the fixed
+    .jax_cache/ of the checkout, whatever the working directory."""
+    import jax
+    from repro.serve import compile_cache
+
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.chdir(tmp_path)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "env-cache"))
+        want = str(tmp_path / "env-cache")
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(repo, ".jax_cache")
+    got = compile_cache.enable_compile_cache()
+    assert got == want
+    assert os.path.isdir(got)
+    if env_dir:
+        # an explicit path does not override the environment either
+        assert compile_cache.enable_compile_cache(str(tmp_path / "x")) == want
+        assert "jax_compilation_cache_dir" not in updates
+    else:
+        assert want == compile_cache.DEFAULT_CACHE_DIR
+        assert updates["jax_compilation_cache_dir"] == want
+    assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
 
 
 def test_campaign_backend_follows_solver_config(monkeypatch):

@@ -212,10 +212,19 @@ def test_detect_hw_precedence(monkeypatch):
     monkeypatch.delenv("REPRO_HW", raising=False)
     assert detect_hw("TPU v5 lite").name == "tpu-v5e"
     assert detect_hw("TPU v4").name == "tpu-v4"
-    assert detect_hw("weird accelerator").name == "tpu-v5e"  # default
     # explicit argument beats the environment override ...
     monkeypatch.setenv("REPRO_HW", "tpu-v5p")
     assert detect_hw("TPU v4").name == "tpu-v4"
     # ... and the environment override beats autodetection
     assert detect_hw().name == "tpu-v5p"
-    assert get_hw("no-such-hw") == HW_SPECS["tpu-v5e"]
+    assert get_hw("tpu-v5e") == HW_SPECS["tpu-v5e"]
+
+
+@pytest.mark.parametrize("resolve", [
+    lambda: detect_hw("weird accelerator"),
+    lambda: get_hw("no-such-hw"),
+], ids=["detect_hw", "get_hw"])
+def test_unknown_hardware_raises(monkeypatch, resolve):
+    monkeypatch.delenv("REPRO_HW", raising=False)
+    with pytest.raises((KeyError, ValueError), match="no hardware spec"):
+        resolve()
