@@ -65,8 +65,8 @@ from . import gray as G
 from . import precision as P
 from .resume import JobState
 from .ryser import (batched_values, batched_values_complex, chain_prod_complex,
-                    chunk_geometry, complex_precision, nw_base_vector,
-                    _final_factor)
+                    chunk_geometry, complex_precision, launch_and_wait,
+                    nw_base_vector, _final_factor)
 from .stepspace import DEFAULT_GEOMETRY, Geometry, plan_slices
 
 __all__ = ["permanent_on_mesh", "slice_sums_on_mesh", "run_campaign",
@@ -485,14 +485,15 @@ def batch_permanents_on_mesh(stack, mesh: Mesh, *,
     T, C, _ = chunk_geometry(n, num_chunks)
     shard = NamedSharding(mesh, P_(axes))
     if is_complex:
-        vr, vi = _dense_batch_mesh_fn_complex(
-            mesh, T, C, complex_precision(precision))(
+        vr, vi = launch_and_wait(
+            _dense_batch_mesh_fn_complex(mesh, T, C,
+                                         complex_precision(precision)),
             jax.device_put(np.ascontiguousarray(stack.real), shard),
             jax.device_put(np.ascontiguousarray(stack.imag), shard))
-        return (np.asarray(vr) + 1j * np.asarray(vi))[:B]
+        return (vr + 1j * vi)[:B]
     dev_stack = jax.device_put(stack, shard)
-    vals = _dense_batch_mesh_fn(mesh, T, C, precision)(dev_stack)
-    return np.asarray(vals)[:B]
+    return launch_and_wait(_dense_batch_mesh_fn(mesh, T, C, precision),
+                           dev_stack)[:B]
 
 
 @lru_cache(maxsize=None)
@@ -591,24 +592,25 @@ def sparse_batch_permanents_on_mesh(sps: list, mesh: Mesh, *,
     T, C, _ = chunk_geometry(n, num_chunks)
     shard = NamedSharding(mesh, P_(axes))
     if backend == "pallas":
-        vals = _sparse_batch_mesh_fn_pallas(mesh, precision)(
+        return launch_and_wait(
+            _sparse_batch_mesh_fn_pallas(mesh, precision),
             jax.device_put(A_stack, shard),
             jax.device_put(rows_stack, shard),
-            jax.device_put(vals_stack, shard))
-        return np.asarray(vals)[:B]
+            jax.device_put(vals_stack, shard))[:B]
     if np.iscomplexobj(vals_stack):
-        vr, vi = _sparse_batch_mesh_fn_complex(
-            mesh, T, C, complex_precision(precision))(
+        vr, vi = launch_and_wait(
+            _sparse_batch_mesh_fn_complex(mesh, T, C,
+                                          complex_precision(precision)),
             jax.device_put(np.ascontiguousarray(A_stack.real), shard),
             jax.device_put(np.ascontiguousarray(A_stack.imag), shard),
             jax.device_put(rows_stack, shard),
             jax.device_put(np.ascontiguousarray(vals_stack.real), shard),
             jax.device_put(np.ascontiguousarray(vals_stack.imag), shard))
-        return (np.asarray(vr) + 1j * np.asarray(vi))[:B]
-    vals = _sparse_batch_mesh_fn(mesh, T, C, precision)(
+        return (vr + 1j * vi)[:B]
+    return launch_and_wait(
+        _sparse_batch_mesh_fn(mesh, T, C, precision),
         jax.device_put(A_stack, shard), jax.device_put(rows_stack, shard),
-        jax.device_put(vals_stack, shard))
-    return np.asarray(vals)[:B]
+        jax.device_put(vals_stack, shard))[:B]
 
 
 class CampaignPaused(Exception):

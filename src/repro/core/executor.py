@@ -49,12 +49,13 @@ Returns per-matrix totals plus :class:`PermanentReport`s and an
 
 from __future__ import annotations
 
-import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any
 
 import numpy as np
 
+from ..utils.spans import span
 from . import ryser as R
 from . import sparyser as S
 from .cache import ResultCache
@@ -131,9 +132,16 @@ class ExecStats:
     # them across calls as ``leaf_timings``)
     timings: dict[str, LeafTiming] = field(default_factory=dict)
 
-    def record_time(self, key: str, seconds: float,
-                    leaves: int = 1) -> None:
-        self.timings.setdefault(key, LeafTiming()).add(seconds, leaves)
+    @contextmanager
+    def dispatch(self, key: str, leaves: int = 1):
+        """One device dispatch, timed as the span ``executor.dispatch``
+        (attrs ``key``, ``leaves``); its seconds go to ``timings`` under
+        the span's ``key``, which the body may correct once it knows
+        which strategy served the leaves."""
+        with span("executor.dispatch", key=key, leaves=leaves) as s:
+            yield s
+        self.timings.setdefault(s.attrs["key"], LeafTiming()).add(
+            s.seconds, leaves)
 
 
 # ---------------------------------------------------------------------------
@@ -200,13 +208,17 @@ class JnpBackend(Backend):
 
     def dense_batch(self, stack, *, precision, num_chunks, geometry=None,
                     ctx=None):
-        return np.asarray(R.perm_ryser_batched(stack, num_chunks=num_chunks,
-                                               precision=precision))
+        if np.iscomplexobj(stack):
+            # the split-plane engine syncs and joins the planes itself
+            return R.perm_ryser_batched(stack, num_chunks=num_chunks,
+                                        precision=precision)
+        return R.launch_and_wait(R.perm_ryser_batched, stack,
+                                 num_chunks=num_chunks, precision=precision)
 
     def sparse_batch(self, sps, *, precision, num_chunks, geometry=None,
                      ctx=None):
-        return np.asarray(S.perm_sparyser_batched(sps, num_chunks=num_chunks,
-                                                  precision=precision))
+        return S.perm_sparyser_batched(sps, num_chunks=num_chunks,
+                                       precision=precision)
 
 
 class PallasBackend(JnpBackend):
@@ -248,16 +260,16 @@ class PallasBackend(JnpBackend):
                     ctx=None):
         if self._supported(stack):
             from ..kernels import ops as K
-            return np.asarray(K.permanent_pallas_batched(
-                stack, precision=precision, geometry=geometry))
+            return R.launch_and_wait(K.permanent_pallas_batched, stack,
+                                     precision=precision, geometry=geometry)
         return None                  # dispatcher falls back + tags downgrade
 
     def sparse_batch(self, sps, *, precision, num_chunks, geometry=None,
                      ctx=None):
         if self._kernel_ok(sps[0].n):
             from ..kernels import ops as K
-            return np.asarray(K.permanent_pallas_sparse_batched(
-                sps, precision=precision, geometry=geometry))
+            return R.launch_and_wait(K.permanent_pallas_sparse_batched, sps,
+                                     precision=precision, geometry=geometry)
         return None                  # tiny bucket: jnp fallback, tagged
 
     def value_backend(self, route, n, *, batched, ctx=None):
@@ -492,22 +504,18 @@ def _run_leaf(leaf: LeafTask, plan: ExecutionPlan, backend: Backend,
             stats.downgrades.append(tag)
         report.dispatch.append(tag)
         sp = S.SparseMatrix.from_dense(leaf.matrix)
-        t0 = time.perf_counter()
-        val = backend.sparse(sp, precision=plan.precision,
-                             num_chunks=cfg.num_chunks,
-                             geometry=leaf.geometry, ctx=ctx)
-        stats.record_time(f"sparse(n={n},{produced})",
-                          time.perf_counter() - t0)
+        with stats.dispatch(f"sparse(n={n},{produced})"):
+            val = backend.sparse(sp, precision=plan.precision,
+                                 num_chunks=cfg.num_chunks,
+                                 geometry=leaf.geometry, ctx=ctx)
     else:
         produced = backend.value_backend(ROUTE_DENSE, n, batched=False,
                                          ctx=ctx)
         report.dispatch.append(f"dense(n={n})")
-        t0 = time.perf_counter()
-        val = backend.dense(leaf.matrix, precision=plan.precision,
-                            num_chunks=cfg.num_chunks,
-                            geometry=leaf.geometry, ctx=ctx)
-        stats.record_time(f"dense(n={n},{produced})",
-                          time.perf_counter() - t0)
+        with stats.dispatch(f"dense(n={n},{produced})"):
+            val = backend.dense(leaf.matrix, precision=plan.precision,
+                                num_chunks=cfg.num_chunks,
+                                geometry=leaf.geometry, ctx=ctx)
     stats.device_dispatches += 1
     stats.scalar_leaves += 1
     return val
@@ -594,14 +602,12 @@ def execute_plan(plan: ExecutionPlan, *, cache: ResultCache | None = None,
         reports[leaf.owner].dispatch.append(
             f"step_sharded(n={leaf.n},slices={spec.total_slices},"
             f"{spec.backend})")
-        t0 = time.perf_counter()
-        val = get_backend("campaign").campaign(
-            leaf.matrix, spec, ctx=distributed_ctx,
-            checkpoint_path=campaign_ckpt(leaf),
-            progress_cb=campaign_progress,
-            max_waves=cfg.campaign_max_waves)
-        stats.record_time(f"step_sharded(n={leaf.n},{spec.backend})",
-                          time.perf_counter() - t0)
+        with stats.dispatch(f"step_sharded(n={leaf.n},{spec.backend})"):
+            val = get_backend("campaign").campaign(
+                leaf.matrix, spec, ctx=distributed_ctx,
+                checkpoint_path=campaign_ckpt(leaf),
+                progress_cb=campaign_progress,
+                max_waves=cfg.campaign_max_waves)
         stats.device_dispatches += 1
         stats.scalar_leaves += 1
         return val
@@ -703,40 +709,30 @@ def execute_plan(plan: ExecutionPlan, *, cache: ResultCache | None = None,
                 totals[leaf.owner] += leaf.coef * complex(val)
                 continue
             tag = f"{route}_batch(n={n},b={len(leaves)})"
-            t_bucket = time.perf_counter()
-            if route == ROUTE_DENSE:
-                stack = np.stack([l.matrix for l in leaves])
-                vals = backend.dense_batch(stack, precision=plan.precision,
-                                           num_chunks=cfg.num_chunks,
-                                           geometry=geometry,
-                                           ctx=distributed_ctx)
+            with stats.dispatch(f"{route}_batch(n={n},{bname})",
+                                leaves=len(leaves)) as d:
+                if route == ROUTE_DENSE:
+                    items = np.stack([l.matrix for l in leaves])
+                    run, run_fallback = backend.dense_batch, \
+                        fallback.dense_batch
+                else:
+                    items = [S.SparseMatrix.from_dense(l.matrix)
+                             for l in leaves]
+                    run, run_fallback = backend.sparse_batch, \
+                        fallback.sparse_batch
+                vals = run(items, precision=plan.precision,
+                           num_chunks=cfg.num_chunks, geometry=geometry,
+                           ctx=distributed_ctx)
                 if vals is None:     # e.g. tiny bucket under pallas
-                    vals = fallback.dense_batch(stack,
-                                                precision=plan.precision,
-                                                num_chunks=cfg.num_chunks)
+                    vals = run_fallback(items, precision=plan.precision,
+                                        num_chunks=cfg.num_chunks)
                     tag = f"{route}_batch(n={n},b={len(leaves)}," \
                           f"{cfg.backend}->{_FALLBACK})"
                     stats.downgrades.append(tag)
                     bname = _FALLBACK   # the fallback produced these values
-            else:
-                sps = [S.SparseMatrix.from_dense(l.matrix) for l in leaves]
-                vals = backend.sparse_batch(sps, precision=plan.precision,
-                                            num_chunks=cfg.num_chunks,
-                                            geometry=geometry,
-                                            ctx=distributed_ctx)
-                if vals is None:
-                    vals = fallback.sparse_batch(sps,
-                                                 precision=plan.precision,
-                                                 num_chunks=cfg.num_chunks)
-                    tag = f"{route}_batch(n={n},b={len(leaves)}," \
-                          f"{cfg.backend}->{_FALLBACK})"
-                    stats.downgrades.append(tag)
-                    bname = _FALLBACK
+                    d.attrs["key"] = f"{route}_batch(n={n},{bname})"
             stats.device_dispatches += 1
             stats.batched_leaves += len(leaves)
-            stats.record_time(f"{route}_batch(n={n},{bname})",
-                              time.perf_counter() - t_bucket,
-                              leaves=len(leaves))
             vals = np.asarray(vals)
             for leaf, v in zip(leaves, vals):
                 v = _scalar(v)

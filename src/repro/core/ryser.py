@@ -29,10 +29,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.spans import span
 from . import gray as G
 from . import precision as P
 
 __all__ = [
+    "launch_and_wait",
     "nw_base_vector",
     "perm_ryser_seq",
     "perm_ryser_chunked",
@@ -610,7 +612,26 @@ def _complex_batched(As: np.ndarray, num_chunks: int, precision: str):
         return As[:, 0, 0]
     if n == 2:
         return As[:, 0, 0] * As[:, 1, 1] + As[:, 0, 1] * As[:, 1, 0]
-    vr, vi = _batched_complex_jit(np.ascontiguousarray(As.real),
-                                  np.ascontiguousarray(As.imag),
-                                  num_chunks, precision)
-    return np.asarray(vr) + 1j * np.asarray(vi)
+    vr, vi = launch_and_wait(_batched_complex_jit,
+                             np.ascontiguousarray(As.real),
+                             np.ascontiguousarray(As.imag),
+                             num_chunks, precision)
+    return vr + 1j * vi
+
+
+def launch_and_wait(program, *args, **kwargs):
+    """Run one device program and bring its result to the host.
+
+    ``program(*args, **kwargs)`` is dispatched under the span
+    ``engine.launch`` (argument transfer, a trace on a cache miss, the
+    enqueue); the host's block on its outputs and their copy back run
+    under ``engine.wait``.  Every batch path calls it at its one device
+    sync, so each device program has exactly one ``engine.wait``.
+    Returns NumPy arrays, a tuple of them for a tuple result.
+    """
+    with span("engine.launch"):
+        out = program(*args, **kwargs)
+    with span("engine.wait"):
+        if isinstance(out, tuple):
+            return tuple(np.asarray(o) for o in out)
+        return np.asarray(out)

@@ -42,6 +42,7 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from ..utils.spans import span
 from .cache import ResultCache
 from .executor import ExecStats, LeafTiming, execute_plan
 from .planner import ExecutionPlan, PermanentReport, SolverConfig, build_plan
@@ -119,12 +120,6 @@ class PermanentSolver:
         # optional JobState -> None callback fired after every
         # checkpointed wave of a step_sharded (campaign) leaf
         self.campaign_progress: Callable | None = None
-        # admission/flush observability hooks (serve/metrics.py installs
-        # these): on_submit(request) fires after a request is enqueued
-        # (before any flush it triggers); on_flush(n, served, seconds)
-        # fires after a bucket flush resolves its futures
-        self.on_submit: Callable[[PermanentRequest], None] | None = None
-        self.on_flush: Callable[[int, int, float], None] | None = None
 
     # -- plan ---------------------------------------------------------------
 
@@ -133,27 +128,32 @@ class PermanentSolver:
         A = np.asarray(A)
         if A.ndim != 2 or A.shape[0] != A.shape[1]:
             raise ValueError(f"square matrix required, got {A.shape}")
-        return build_plan([A], self.config, batched=False)
+        with span("solver.plan"):
+            return build_plan([A], self.config, batched=False)
 
     def plan_batch(self, As: Sequence) -> ExecutionPlan:
         """Bucketed batch plan: same-size same-route leaves share one
         device program (vmapped locally, or batch-axis-sharded over the
         mesh when the solver holds a ``distributed_ctx`` and the backend
         is ``distributed``/``distributed_batch``)."""
-        return build_plan(list(As), self.config, batched=True)
+        with span("solver.plan"):
+            return build_plan(list(As), self.config, batched=True)
 
     # -- execute ------------------------------------------------------------
 
     def execute(self, plan: ExecutionPlan, *, return_report: bool = False):
         """Dispatch a plan; scalar plans return a Python scalar, batch
         plans a (B,) ndarray (complex128 when the plan is complex)."""
-        totals, reports, stats = execute_plan(
-            plan, cache=self.cache, distributed_ctx=self.distributed_ctx,
-            campaign_progress=self.campaign_progress)
-        self._merge_stats(stats)
-        out = totals if plan.is_complex else np.real(totals)
-        for i, r in enumerate(reports):
-            r.value = complex(out[i]) if plan.is_complex else float(out[i])
+        with span("solver.execute"):
+            totals, reports, stats = execute_plan(
+                plan, cache=self.cache,
+                distributed_ctx=self.distributed_ctx,
+                campaign_progress=self.campaign_progress)
+            self._merge_stats(stats)
+            out = totals if plan.is_complex else np.real(totals)
+            for i, r in enumerate(reports):
+                r.value = complex(out[i]) if plan.is_complex \
+                    else float(out[i])
         if not plan.batched and plan.num_matrices == 1:
             value = reports[0].value
             return (value, reports[0]) if return_report else value
@@ -175,8 +175,6 @@ class PermanentSolver:
         t0, reqs = self._queue.setdefault(A.shape[0],
                                           (self._clock(), []))
         reqs.append(req)
-        if self.on_submit is not None:
-            self.on_submit(req)
         if len(reqs) >= self.config.queue_max_batch:
             self._flush_bucket(A.shape[0])
         self.poll()
@@ -206,15 +204,12 @@ class PermanentSolver:
             return 0
         # plan + execute BEFORE dequeuing: if either raises, the bucket
         # stays queued and the pending futures remain resolvable
-        t0 = time.perf_counter()
         plan = self.plan_batch([r.matrix for r in reqs])
         _, reports = self.execute(plan, return_report=True)
         self._queue.pop(n, None)
         for req, report in zip(reqs, reports):
             req._resolve(report.value, report)
         self.flushes += 1
-        if self.on_flush is not None:
-            self.on_flush(n, len(reqs), time.perf_counter() - t0)
         return len(reqs)
 
     # -- accounting ---------------------------------------------------------

@@ -24,8 +24,9 @@ import numpy as np
 
 from . import precision as P
 from .ryser import (chain_prod, chain_prod_complex, chunk_geometry,
-                    complex_precision, nw_base_vector, rank1_chunk_init,
-                    tf_tree_sum, _CEGSchedules, _final_factor)
+                    complex_precision, launch_and_wait, nw_base_vector,
+                    rank1_chunk_init, tf_tree_sum, _CEGSchedules,
+                    _final_factor)
 
 __all__ = ["SparseMatrix", "perm_sparyser_chunked", "perm_sparyser_batched",
            "sparse_batched_values", "sparse_batched_values_complex",
@@ -446,14 +447,15 @@ def perm_sparyser_batched(sps: list[SparseMatrix], num_chunks: int = 4096,
     T, C, _ = chunk_geometry(n, num_chunks)
     A_stack, rows_stack, vals_stack = pack_padded_ccs(sps)
     if np.iscomplexobj(vals_stack):
-        vr, vi = _sparse_batched_complex_jit(
+        vr, vi = launch_and_wait(
+            _sparse_batched_complex_jit,
             jnp.asarray(np.ascontiguousarray(A_stack.real)),
             jnp.asarray(np.ascontiguousarray(A_stack.imag)),
             jnp.asarray(rows_stack),
             jnp.asarray(np.ascontiguousarray(vals_stack.real)),
             jnp.asarray(np.ascontiguousarray(vals_stack.imag)),
             T, C, precision)
-        return np.asarray(vr) + 1j * np.asarray(vi)
-    out = _sparse_batched_jit(jnp.asarray(A_stack), jnp.asarray(rows_stack),
-                              jnp.asarray(vals_stack), T, C, precision)
-    return np.asarray(out)
+        return vr + 1j * vi
+    return launch_and_wait(_sparse_batched_jit, jnp.asarray(A_stack),
+                           jnp.asarray(rows_stack), jnp.asarray(vals_stack),
+                           T, C, precision)

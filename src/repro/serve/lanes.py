@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import enum
 import itertools
+import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Iterator
@@ -96,6 +97,8 @@ class ServeTicket:
         self.is_complex = bool(np.iscomplexobj(matrix))
         self.lane = lane
         self.t_submit = t_submit             # admission timestamp
+        # the span clock at the submit call: serve.queue starts here
+        self.t_queued = time.perf_counter()
         self.deadline = deadline             # absolute, or None
         self.cost = request_cost(self.n)
         self.status = QUEUED

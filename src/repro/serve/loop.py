@@ -54,6 +54,7 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from ..utils.spans import record, span
 from .compile_cache import (compile_stats, enable_compile_cache,
                             quantized_batches, warmup)
 from .lanes import (DEFAULT_LANES, LaneQueue, LaneSpec, ServeTicket,
@@ -134,7 +135,8 @@ class PermanentService:
         self._filler_rng = np.random.default_rng(filler_seed)
         self._ladder = quantized_batches(self.scfg.max_batch)
         # (key, served, plan+execute seconds, trigger) per dispatch --
-        # the wrapper in launch/serve.py derives its latency report here
+        # the wrapper in launch/serve.py derives its latency report here;
+        # each dispatch is also the span ``serve.dispatch``
         self.dispatch_log: list[tuple[tuple, int, float, str]] = []
 
         if self.scfg.compile_cache_dir:
@@ -337,27 +339,36 @@ class PermanentService:
         return None, None
 
     def _dispatch(self, key: tuple, trigger: str) -> int:
-        tickets = self._queue.take(key, self.scfg.max_batch)
         n, is_complex = key
-        mats = [t.matrix for t in tickets]
-        if self.scfg.quantize_buckets:
-            target = next(b for b in self._ladder if b >= len(mats))
-            for _ in range(target - len(mats)):
-                F = self._filler_rng.uniform(-1.0, 1.0, (n, n))
-                if is_complex:
-                    F = F + 1j * self._filler_rng.uniform(-1.0, 1.0,
-                                                          (n, n))
-                mats.append(F)
-        t0 = time.perf_counter()
-        plan = self.solver.plan_batch(mats)
-        out = self.solver.execute(plan)
-        dt = time.perf_counter() - t0
-        t_done = self._clock()
-        for t, v in zip(tickets, out):      # padded tail values discarded
-            t._resolve(complex(v) if t.is_complex else float(v), t_done)
-            self.metrics.record_complete(t)
-        self.metrics.record_dispatch(len(tickets), self.scfg.max_batch)
-        self.dispatch_log.append((key, len(tickets), dt, trigger))
+        with span("serve.dispatch", n=n, trigger=trigger) as d:
+            tickets = self._queue.take(key, self.scfg.max_batch)
+            mats = [t.matrix for t in tickets]
+            with span("serve.pad") as pad:
+                if self.scfg.quantize_buckets:
+                    target = next(b for b in self._ladder if b >= len(mats))
+                    for _ in range(target - len(mats)):
+                        F = self._filler_rng.uniform(-1.0, 1.0, (n, n))
+                        if is_complex:
+                            F = F + 1j * self._filler_rng.uniform(
+                                -1.0, 1.0, (n, n))
+                        mats.append(F)
+            d.attrs.update(served=len(tickets), lanes=len(mats))
+            plan = self.solver.plan_batch(mats)
+            out = self.solver.execute(plan)
+            with span("serve.resolve") as resolve:
+                t_done = self._clock()
+                for t, v in zip(tickets, out):  # padded tail discarded
+                    t._resolve(complex(v) if t.is_complex else float(v),
+                               t_done)
+                    self.metrics.record_complete(t)
+                self.metrics.record_dispatch(len(tickets),
+                                             self.scfg.max_batch)
+            # plan + execute: the time between its neighbours
+            self.dispatch_log.append((key, len(tickets),
+                                      resolve.t0 - pad.t1, trigger))
+        for t in tickets:
+            record("serve.queue", t.t_queued, d.t0, ticket=t.id,
+                   dispatch=d.id)
         if self._campaign is not None:
             self._advance_campaign(self._campaign.waves)
         return len(tickets)

@@ -39,7 +39,8 @@ Snapshot schema (``ServeMetrics.snapshot()``)::
 Quantiles come from fixed log-spaced bucket histograms (no sample
 retention -- bounded memory under millions of requests); ``p50``/``p99``
 are bucket upper-bound estimates, conservative by at most one bucket
-width (~26% with the default 10-buckets-per-decade layout).
+width: at most 4.9% above the sample's own quantile with the default
+48-buckets-per-decade layout of the latency histograms.
 
 :func:`start_metrics_server` serves the snapshot as JSON over stdlib
 HTTP (``GET /metrics``) for scraping; ``ServeMetrics.log_line()`` is the
@@ -69,7 +70,7 @@ class Histogram:
     """
 
     def __init__(self, lo: float = 1e-6, hi: float = 1e5,
-                 per_decade: int = 10):
+                 per_decade: int = 48):
         if not (0 < lo < hi):
             raise ValueError(f"need 0 < lo < hi, got {lo}, {hi}")
         import math
