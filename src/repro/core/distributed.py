@@ -66,7 +66,7 @@ from . import precision as P
 from .resume import JobState
 from .ryser import (batched_values, batched_values_complex, chain_prod_complex,
                     chunk_geometry, complex_precision, launch_and_wait,
-                    nw_base_vector, _final_factor)
+                    nw_base_vector, signed_column, _final_factor)
 from .stepspace import DEFAULT_GEOMETRY, Geometry, plan_slices
 
 __all__ = ["permanent_on_mesh", "slice_sums_on_mesh", "run_campaign",
@@ -170,8 +170,7 @@ def _dyn_chunk_partials(A, first_chunk, T: int, C: int, precision: str):
         X, acc = carry
         col_j, bit, midf, par = inputs
         sign_bits = bit ^ (midf & lane_bitk)
-        s = (2 * sign_bits - 1).astype(dtype)
-        X = tuple(x + M[:, col_j][:, None] * s[None, :]
+        X = tuple(x + signed_column(M[:, col_j], sign_bits)
                   for x, M in zip(X, planes))
         acc = tuple(accum(a, jnp.where(par == 1, -p, p))
                     for a, p in zip(acc, product(X)))
