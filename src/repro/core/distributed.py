@@ -52,6 +52,7 @@ APIs:
 from __future__ import annotations
 
 import math
+from collections import Counter
 from dataclasses import dataclass
 from functools import lru_cache, partial
 
@@ -61,6 +62,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P_
 
 from ..utils.compat import shard_map
+from ..utils.spans import span
 from . import gray as G
 from . import precision as P
 from .resume import JobState
@@ -363,8 +365,9 @@ def slice_sums_on_mesh(A, mesh: Mesh, slice_ids: np.ndarray, *,
     axes = tuple(mesh.axis_names)
     dev_slices = jax.device_put(slice_ids.reshape(D, 1),
                                 NamedSharding(mesh, P_(axes)))
-    his, los = _wave_fn(mesh, chunks_per_slice, chunk_size,
-                        precision, backend, geometry)(_planes(A), dev_slices)
+    his, los = launch_and_wait(
+        _wave_fn(mesh, chunks_per_slice, chunk_size, precision, backend,
+                 geometry), _planes(A), dev_slices)
     return _join(his), _join(los)
 
 
@@ -631,7 +634,8 @@ def run_campaign(A, mesh: Mesh, *, total_slices: int, chunks_per_slice: int,
                  backend: str = "jnp", geometry: Geometry | None = None,
                  checkpoint_path: str | None = None,
                  state: JobState | None = None, progress_cb=None,
-                 max_waves: int | None = None, max_wave_retries: int = 2):
+                 max_waves: int | None = None, max_wave_retries: int = 2,
+                 counters: Counter | None = None):
     """Execute a step-space campaign in device-count-sized waves.
 
     The unit of work is a *slice* (contiguous block of ``chunks_per_slice``
@@ -656,6 +660,13 @@ def run_campaign(A, mesh: Mesh, *, total_slices: int, chunks_per_slice: int,
     paused the run with slices still pending (callers that need the
     pause as control flow raise :class:`CampaignPaused`, e.g. the
     executor's ``CampaignBackend``).
+
+    Spans (``repro.utils.spans``): ``campaign.wave`` (``wave``,
+    ``slices``, ``chips``) around each wave, holding its
+    ``engine.launch``/``engine.wait`` and its ``campaign.checkpoint``
+    (``bytes``); ``campaign.reduce`` around the final reduce and the host
+    epilogue.  ``counters`` gains ``waves``, ``slices``,
+    ``retried_waves`` and ``checkpoint_bytes``.
     """
     A = np.asarray(A)
     n = A.shape[0]
@@ -666,6 +677,7 @@ def run_campaign(A, mesh: Mesh, *, total_slices: int, chunks_per_slice: int,
             backend=backend, chunks_per_slice=chunks_per_slice,
             chunk_size=chunk_size,
             geometry=geometry.tag() if geometry is not None else "-")
+    counters = Counter() if counters is None else counters
     waves = 0
     retries = 0
     while True:
@@ -676,36 +688,44 @@ def run_campaign(A, mesh: Mesh, *, total_slices: int, chunks_per_slice: int,
             return None, state
         wave = pending[:D]
         ids = np.array(wave + [-1] * (D - len(wave)), dtype=np.int32)
-        try:
-            his, los = slice_sums_on_mesh(
-                A, mesh, ids, chunks_per_slice=chunks_per_slice,
-                chunk_size=chunk_size, precision=precision, backend=backend,
-                geometry=geometry)
-        except Exception:
-            # preempted/straggling wave: nothing recorded, its slices
-            # stay pending and the next iteration re-forms the wave
-            retries += 1
-            if retries > max_wave_retries:
-                raise
-            continue
-        retries = 0
-        # discard sentinel-padded lanes explicitly: only the wave's own
-        # slice ids are recorded
-        state.record_wave(wave, his[:len(wave)], los[:len(wave)])
-        waves += 1
-        if checkpoint_path:
-            state.save(checkpoint_path)
+        with span("campaign.wave", wave=waves, slices=len(wave), chips=D):
+            try:
+                his, los = slice_sums_on_mesh(
+                    A, mesh, ids, chunks_per_slice=chunks_per_slice,
+                    chunk_size=chunk_size, precision=precision,
+                    backend=backend, geometry=geometry)
+            except Exception:
+                # preempted/straggling wave: nothing recorded, its slices
+                # stay pending and the next iteration re-forms the wave
+                retries += 1
+                counters["retried_waves"] += 1
+                if retries > max_wave_retries:
+                    raise
+                continue
+            retries = 0
+            # discard sentinel-padded lanes explicitly: only the wave's
+            # own slice ids are recorded
+            state.record_wave(wave, his[:len(wave)], los[:len(wave)])
+            waves += 1
+            counters["waves"] += 1
+            counters["slices"] += len(wave)
+            if checkpoint_path:
+                with span("campaign.checkpoint") as ck:
+                    ck.attrs["bytes"] = state.save(checkpoint_path)
+                counters["checkpoint_bytes"] += ck.attrs["bytes"]
         if progress_cb:
             progress_cb(state)
 
-    hi, lo = state.reduce()
-    # the epilogue is host arithmetic: a few adds, and no c128 value
-    # ever reaches the device
-    p0 = np.prod(nw_base_vector(A))  # permlint: disable=PL001  # length-n product, shape set by the matrix
-    total = P.tf_add_acc(P.TwoFloat(np.asarray(hi), np.asarray(lo)), p0)
-    # .item(): float for real jobs (the legacy return type), complex
-    # for complex jobs
-    value = np.asarray(P.tf_value(total)).item() * _final_factor(n)
+    with span("campaign.reduce"):
+        hi, lo = state.reduce()
+        # the epilogue is host arithmetic: a few adds, and no c128 value
+        # ever reaches the device
+        p0 = np.prod(nw_base_vector(A))  # permlint: disable=PL001  # length-n product, shape set by the matrix
+        total = P.tf_add_acc(P.TwoFloat(np.asarray(hi), np.asarray(lo)),
+                             p0)
+        # .item(): float for real jobs (the legacy return type), complex
+        # for complex jobs
+        value = np.asarray(P.tf_value(total)).item() * _final_factor(n)
     return value, state
 
 

@@ -49,6 +49,7 @@ Returns per-matrix totals plus :class:`PermanentReport`s and an
 
 from __future__ import annotations
 
+from collections import Counter
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any
@@ -131,6 +132,8 @@ class ExecStats:
     # through the one snapshot schema; PermanentSolver.stats() aggregates
     # them across calls as ``leaf_timings``)
     timings: dict[str, LeafTiming] = field(default_factory=dict)
+    # campaign leaves: waves, slices, retried_waves, checkpoint_bytes
+    campaign: Counter = field(default_factory=Counter)
 
     @contextmanager
     def dispatch(self, key: str, leaves: int = 1):
@@ -382,15 +385,17 @@ class CampaignBackend(Backend):
     ``SolverConfig.campaign_checkpoint`` after each wave, fixed-order
     final reduce.  A ``campaign_max_waves`` budget that expires with
     slices pending raises :class:`~repro.core.distributed.CampaignPaused`
-    through ``execute_plan`` (the checkpoint holds the progress).
+    through ``execute_plan`` (the checkpoint holds the progress).  A
+    completed leaf's checkpoint stays until the next job under the same
+    path starts afresh over it.
     """
 
     name = "campaign"
 
     def campaign(self, M: np.ndarray, spec: CampaignSpec, *,
                  ctx: Any | None = None, checkpoint_path: str | None = None,
-                 progress_cb=None,
-                 max_waves: int | None = None) -> complex | float:
+                 progress_cb=None, max_waves: int | None = None,
+                 counters: Counter | None = None) -> complex | float:
         from . import distributed as Dm
         mesh = _ctx_mesh(ctx)
         if mesh is None:
@@ -403,7 +408,8 @@ class CampaignBackend(Backend):
             chunk_size=spec.chunk_size, precision=spec.precision,
             backend=spec.backend, geometry=spec.geometry,
             checkpoint_path=checkpoint_path,
-            progress_cb=progress_cb, max_waves=max_waves)
+            progress_cb=progress_cb, max_waves=max_waves,
+            counters=counters)
         if value is None:
             raise Dm.CampaignPaused(state)
         return _scalar(value)
@@ -607,7 +613,7 @@ def execute_plan(plan: ExecutionPlan, *, cache: ResultCache | None = None,
                 leaf.matrix, spec, ctx=distributed_ctx,
                 checkpoint_path=campaign_ckpt(leaf),
                 progress_cb=campaign_progress,
-                max_waves=cfg.campaign_max_waves)
+                max_waves=cfg.campaign_max_waves, counters=stats.campaign)
         stats.device_dispatches += 1
         stats.scalar_leaves += 1
         return val

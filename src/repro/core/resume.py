@@ -7,7 +7,10 @@ per-slice twofloat partial sums.  Slices are independent addends, so:
 * a crashed job resumes from the last snapshot, losing at most one wave;
 * a resumed job may use a different device count (elastic) -- waves are
   re-formed from the pending slice set;
-* stragglers only delay their own wave; completed slices are never redone.
+* stragglers only delay their own wave; completed slices are never redone;
+* a finished job's checkpoint stays, so a rerun of that job reads its
+  value back, until another job (another matrix or configuration) starts
+  afresh over it: back-to-back jobs may share one configured path.
 
 Config safety: partial sums are only meaningful under the exact
 (precision, backend, chunk geometry) that computed them -- merging a
@@ -119,8 +122,19 @@ class JobState:
                        chunks_per_slice: int = 0,
                        chunk_size: int = 0,
                        geometry: str = "-") -> "JobState":
+        want = {"precision": precision, "backend": backend,
+                "chunks_per_slice": chunks_per_slice,
+                "chunk_size": chunk_size, "geometry": geometry}
         if path and os.path.exists(path):
             state = JobState.load(path)
+            if state.finished and (
+                    state.fingerprint != matrix_fingerprint(matrix)
+                    or state.total_slices != total_slices
+                    or any(getattr(state, k) != want[k]
+                           for k in _CONFIG_KEYS)):
+                # another job's finished state: its value went to its
+                # caller and nothing of it merges here
+                return JobState.create(matrix, total_slices, **want)
             if state.fingerprint != matrix_fingerprint(matrix):
                 raise ValueError(
                     "checkpoint belongs to a different matrix "
@@ -130,9 +144,6 @@ class JobState:
                     f"checkpoint has {state.total_slices} slices, plan has "
                     f"{total_slices}; re-plan with the original slice "
                     "decomposition or finish with the code that wrote it")
-            want = {"precision": precision, "backend": backend,
-                    "chunks_per_slice": chunks_per_slice,
-                    "chunk_size": chunk_size, "geometry": geometry}
             bad = [k for k in _CONFIG_KEYS
                    if getattr(state, k) != want[k]]
             if bad:
@@ -145,12 +156,13 @@ class JobState:
                     f"({detail}); resume with the original config or "
                     "restart from scratch")
             return state
-        return JobState.create(matrix, total_slices, precision=precision,
-                               backend=backend,
-                               chunks_per_slice=chunks_per_slice,
-                               chunk_size=chunk_size, geometry=geometry)
+        return JobState.create(matrix, total_slices, **want)
 
     # ------------------------------------------------------------------
+    @property
+    def finished(self) -> bool:
+        return bool(self.done.all())
+
     def pending_slices(self) -> list[int]:
         return [int(i) for i in np.nonzero(~self.done)[0]]
 
@@ -179,7 +191,8 @@ class JobState:
         s, e = _two_sum_host(hi, lo)
         return s, e
 
-    def save(self, path: str) -> None:
+    def save(self, path: str) -> int:
+        """Write the state to ``path`` atomically; returns its bytes."""
         d = os.path.dirname(os.path.abspath(path))
         os.makedirs(d, exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=d, suffix=".npz.tmp")
@@ -196,10 +209,12 @@ class JobState:
             produced = tmp if tmp.endswith(".npz") else tmp + ".npz"
             if os.path.exists(produced) and produced != tmp:
                 os.replace(produced, tmp)
+            size = os.path.getsize(tmp)
             os.replace(tmp, path)
         finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
+        return size
 
 
 def _two_sum_host(a: float, b: float):

@@ -223,6 +223,7 @@ class PermanentSolver:
         t.cache_hits += s.cache_hits
         t.cache_misses += s.cache_misses
         t.downgrades.extend(s.downgrades)
+        t.campaign.update(s.campaign)
         for key, lt in s.timings.items():
             t.timings.setdefault(key, LeafTiming()).merge(lt)
 
@@ -233,7 +234,8 @@ class PermanentSolver:
         by dispatch-site key (``dense_batch(n=12,jnp)`` -> count / leaves
         / total_s / max_s) -- the same shape ``serve.metrics`` exports in
         its snapshot schema, so benchmarks and the service log line read
-        identical counters.
+        identical counters.  ``campaign`` counts the waves, slices,
+        retried waves and checkpoint bytes of step_sharded leaves.
         """
         out = {"device_dispatches": self._stats.device_dispatches,
                "batched_leaves": self._stats.batched_leaves,
@@ -242,6 +244,8 @@ class PermanentSolver:
                "downgrades": list(self._stats.downgrades),
                "flushes": self.flushes,
                "pending": self.pending,
+               "campaign": {k: self._stats.campaign[k] for k in (
+                   "waves", "slices", "retried_waves", "checkpoint_bytes")},
                "leaf_timings": {k: t.to_json()
                                 for k, t in sorted(
                                     self._stats.timings.items())}}

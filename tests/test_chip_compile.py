@@ -79,6 +79,18 @@ def test_campaign_wave_compiles(topo, devices, planes):
     wave.lower(A, ids).compile()
 
 
+def test_campaign_wave_compiles_at_the_record_cells_size(topo):
+    """The wave of the benchmark's four-chip campaign cell: real, n = 31
+    (the smallest n the planner's threshold routes to the campaign)."""
+    n = 31
+    _, cps, chunk = plan_slices(n, 64, 1, 1024)
+    mesh = Mesh(np.array(topo.devices[:4]), ("step",))
+    A = (_sds((n, n), jnp.float64, NamedSharding(mesh, PartitionSpec())),)
+    ids = _sds((4, 1), jnp.int32, NamedSharding(mesh, PartitionSpec("step")))
+    wave = Dm._wave_fn(mesh, cps, chunk, "dq_acc", "jnp", None)
+    wave.lower(A, ids).compile()
+
+
 @pytest.mark.parametrize("entry", ["baseline", "batched", "grid"])
 def test_pallas_dense_f32_compiles_with_mosaic(one_chip, entry):
     n, n_pad = 24, 24
