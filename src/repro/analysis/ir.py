@@ -123,11 +123,13 @@ def _build(entry: Entry, precision: str, B: int):
         from ..core import ryser
         if entry.dtype == "c128":
             # complex runs as split planes, the scalar entry as the B=1
-            # batch program (perm_ryser_chunked -> _complex_batched)
+            # batch program (perm_ryser_chunked -> _complex_batched),
+            # its live count a traced argument
             Bc = 1 if entry.arity == "scalar" else B
-            fn = lambda Ar, Ai: ryser.batched_values_complex(
-                Ar, Ai, T, C, precision)
-            return fn, (_sds((Bc, n, n), f64), _sds((Bc, n, n), f64))
+            fn = lambda Ar, Ai, live: ryser.batched_values_complex(
+                Ar, Ai, live, T, C, precision)
+            return fn, (_sds((Bc, n, n), f64), _sds((Bc, n, n), f64),
+                        _sds((), i32))
         if entry.arity == "scalar":
             fn = lambda A: ryser.perm_ryser_chunked(
                 A, num_chunks=NUM_CHUNKS, precision=precision)

@@ -15,8 +15,8 @@ Post-campaign-refactor layering -- this module owns every *mesh program*
   uninterrupted one.
 * **batch-axis split** (many moderate matrices): ``batch_permanents_on_mesh``
   / ``sparse_batch_permanents_on_mesh`` shard a same-size bucket's
-  leading axis; each device owns whole matrices, ragged tails are padded
-  and masked, and the per-device body shares the single-device engines'
+  leading axis; each device owns whole matrices, padding is masked on
+  the host, and the per-device body shares the single-device engines'
   trace, so sharded values are bit-identical to the ``jnp`` backend per
   precision mode.
 * **dispatch** happens one layer up: ``core.planner`` routes a leaf to
@@ -68,7 +68,8 @@ from . import precision as P
 from .resume import JobState
 from .ryser import (batched_values, batched_values_complex, chain_prod_complex,
                     chunk_geometry, complex_precision, launch_and_wait,
-                    nw_base_vector, signed_column, _final_factor)
+                    nw_base_vector, pow2_slots, signed_column,
+                    _final_factor)
 from .stepspace import DEFAULT_GEOMETRY, Geometry, plan_slices
 
 __all__ = ["permanent_on_mesh", "slice_sums_on_mesh", "run_campaign",
@@ -442,14 +443,16 @@ def _dense_batch_mesh_fn(mesh: Mesh, T: int, C: int, precision: str):
 def _dense_batch_mesh_fn_complex(mesh: Mesh, T: int, C: int, precision: str):
     """Split-plane complex analogue of ``_dense_batch_mesh_fn``: the body
     is ``ryser.batched_values_complex`` verbatim over each device's local
-    (re, im) sub-stacks -- one trace shared with the jnp backend."""
+    (re, im) sub-stacks and its live count -- one trace shared with the
+    jnp backend."""
     axes = tuple(mesh.axis_names)
 
-    def body(local_r, local_i):          # (B/D, n, n) x2 per device
-        return batched_values_complex(local_r, local_i, T, C, precision)
+    def body(local_r, local_i, local_live):  # (B/D, n, n) x2, (1,)
+        return batched_values_complex(local_r, local_i, local_live[0], T, C,
+                                      precision)
 
     return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(P_(axes), P_(axes)),
+                             in_specs=(P_(axes), P_(axes), P_(axes)),
                              out_specs=(P_(axes), P_(axes)),
                              check_vma=False))
 
@@ -461,12 +464,17 @@ def batch_permanents_on_mesh(stack, mesh: Mesh, *,
 
     Each device computes the full 2^{n-1} step space for the matrices it
     owns (data parallelism over the bucket), so there is no cross-device
-    reduction at all; ragged tails (B not divisible by the device count)
-    are padded with zero matrices whose results are discarded on the
-    host.  Values are bit-identical to ``ryser.perm_ryser_batched`` for
-    every precision mode -- the per-device body shares its trace.
+    reduction at all.  Values are bit-identical to
+    ``ryser.perm_ryser_batched`` for every precision mode -- the
+    per-device body shares its trace.
     Complex stacks shard their split (re, im) planes through
     ``ryser.batched_values_complex`` under the same contract.
+
+    The bucket is dealt round-robin (matrix i to shard i % D) into
+    shards of one extent, the rung of ceil(B / D), so a batch of any
+    size runs one of a ladder of programs and the busiest shard holds
+    ceil(B / D) matrices.  Each shard's zero tail is padding: the real
+    body computes it, the complex body runs it as dead slots.
     """
     stack = np.asarray(stack)
     if stack.ndim != 3 or stack.shape[1] != stack.shape[2]:
@@ -478,11 +486,13 @@ def batch_permanents_on_mesh(stack, mesh: Mesh, *,
         return np.asarray(stack[:, 0, 0] * stack[:, 1, 1]
                           + stack[:, 0, 1] * stack[:, 1, 0])
     is_complex = np.iscomplexobj(stack)
-    stack = stack.astype(np.complex128 if is_complex else np.float64)
-    pad = _batch_pad(B, mesh)
-    if pad:
-        stack = np.concatenate(
-            [stack, np.zeros((pad, n, n), stack.dtype)], axis=0)
+    D = math.prod(mesh.devices.shape)
+    extent, live = _shard_layout(B, D)
+    shard_of, slot = np.arange(B) % D, np.arange(B) // D
+    dealt = np.zeros((D, extent, n, n),
+                     np.complex128 if is_complex else np.float64)
+    dealt[shard_of, slot] = stack
+    dealt = dealt.reshape(D * extent, n, n)
     axes = tuple(mesh.axis_names)
     T, C, _ = chunk_geometry(n, num_chunks)
     shard = NamedSharding(mesh, P_(axes))
@@ -490,12 +500,22 @@ def batch_permanents_on_mesh(stack, mesh: Mesh, *,
         vr, vi = launch_and_wait(
             _dense_batch_mesh_fn_complex(mesh, T, C,
                                          complex_precision(precision)),
-            jax.device_put(np.ascontiguousarray(stack.real), shard),
-            jax.device_put(np.ascontiguousarray(stack.imag), shard))
-        return (vr + 1j * vi)[:B]
-    dev_stack = jax.device_put(stack, shard)
-    return launch_and_wait(_dense_batch_mesh_fn(mesh, T, C, precision),
-                           dev_stack)[:B]
+            jax.device_put(np.ascontiguousarray(dealt.real), shard),
+            jax.device_put(np.ascontiguousarray(dealt.imag), shard),
+            jax.device_put(live.astype(np.int32), shard),
+            attrs={"slots": D * extent, "live": B})
+        vals = vr + 1j * vi
+    else:
+        vals = launch_and_wait(_dense_batch_mesh_fn(mesh, T, C, precision),
+                               jax.device_put(dealt, shard))
+    return vals.reshape(D, extent)[shard_of, slot]
+
+
+def _shard_layout(B: int, D: int) -> tuple[int, np.ndarray]:
+    """Each shard's program extent and its live count for B matrices
+    dealt round-robin over D shards."""
+    return (pow2_slots(-(-B // D)),
+            np.bincount(np.arange(B) % D, minlength=D))
 
 
 @lru_cache(maxsize=None)

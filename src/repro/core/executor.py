@@ -17,7 +17,11 @@ values in bucket order, or ``None`` to signal "unsupported for this
 bucket" -- the dispatcher then re-runs the bucket on the ``jnp``
 strategy and tags the downgrade as ``{route}_batch(...,<cfg>->jnp)``
 (e.g. ``distributed->jnp`` when no mesh/ctx is attached; complex stacks
-are first-class on every strategy and no longer downgrade).  ``ctx`` is
+are first-class on every strategy and no longer downgrade).  A dense
+bucket runs in the program of its rung (``ryser.pow2_slots``), padded
+with zero matrices below this dispatcher's dedup and cache, so every
+bucket size shares one of a ladder of programs; the complex jnp and
+mesh engines run those slots dead, at no Gray steps.  ``ctx`` is
 the ``distributed_ctx`` threaded
 through :func:`execute_plan`: a ``jax.sharding.Mesh`` or any object with
 a ``.mesh`` attribute (``core.distributed.DistributedPermanent``);
@@ -212,11 +216,13 @@ class JnpBackend(Backend):
     def dense_batch(self, stack, *, precision, num_chunks, geometry=None,
                     ctx=None):
         if np.iscomplexobj(stack):
-            # the split-plane engine syncs and joins the planes itself
+            # the split-plane engine rounds the stack up to its rung with
+            # dead slots, syncs and joins the planes itself
             return R.perm_ryser_batched(stack, num_chunks=num_chunks,
                                         precision=precision)
-        return R.launch_and_wait(R.perm_ryser_batched, stack,
-                                 num_chunks=num_chunks, precision=precision)
+        return R.launch_and_wait(R.perm_ryser_batched, R.pad_to_rung(stack),
+                                 num_chunks=num_chunks,
+                                 precision=precision)[:len(stack)]
 
     def sparse_batch(self, sps, *, precision, num_chunks, geometry=None,
                      ctx=None):
@@ -263,8 +269,10 @@ class PallasBackend(JnpBackend):
                     ctx=None):
         if self._supported(stack):
             from ..kernels import ops as K
-            return R.launch_and_wait(K.permanent_pallas_batched, stack,
-                                     precision=precision, geometry=geometry)
+            return R.launch_and_wait(K.permanent_pallas_batched,
+                                     R.pad_to_rung(stack),
+                                     precision=precision,
+                                     geometry=geometry)[:len(stack)]
         return None                  # dispatcher falls back + tags downgrade
 
     def sparse_batch(self, sps, *, precision, num_chunks, geometry=None,
@@ -287,9 +295,10 @@ class DistributedBatchBackend(JnpBackend):
 
     ``dense_batch``/``sparse_batch`` shard a same-size bucket's leading
     axis over the mesh -- matrices replicated per shard (each device owns
-    whole matrices, no psum), ragged tails padded to the device count and
-    masked on the host; complex buckets shard their split (re, im)
-    planes through the same shard_map bodies.  Needs a mesh through
+    whole matrices, no psum), a dense bucket dealt round-robin into
+    shards of its rung, a sparse one's ragged tail padded to the device
+    count, both masked on the host; complex buckets shard their split
+    (re, im) planes through the same shard_map bodies.  Needs a mesh through
     ``ctx``; without one every bucket downgrades to ``jnp`` with a tag.
     Scalar leaves (ragged singletons) use the plain jnp engines -- a
     one-matrix bucket has nothing to shard.

@@ -539,7 +539,7 @@ def chunk_partial_sums_complex(Ar, Ai, T: int, C: int,
             P.TwoFloat(acc_i[0], acc_i[1]), base)
 
 
-def batched_values_complex(Ars, Ais, T: int, C: int, precision: str):
+def batched_values_complex(Ars, Ais, live, T: int, C: int, precision: str):
     """Traced (re, im) value pair for a (B, n, n) split-plane complex stack.
 
     The complex analogue of ``batched_values``: the single traced body
@@ -549,12 +549,16 @@ def batched_values_complex(Ars, Ais, T: int, C: int, precision: str):
     ``tf_tree_sum``'s fixed-order per-component reductions is what makes
     sharded complex values bit-identical to local ones, mirroring the real
     path's guarantee.  Returns ``(values_re, values_im)`` of shape (B,).
+
+    ``live`` (a traced int32 scalar, 0 <= live <= B) is how many leading
+    slots hold real work: only those run the Gray-step scan, and the
+    dead tail reads zero.  A padded program then costs what it serves,
+    and one compiled program serves every live count at its extent.
     """
     precision = complex_precision(precision)
     n = Ars.shape[1]
 
-    def one(planes):
-        ar, ai = planes
+    def one(ar, ai):
         parts_r, parts_i, (p0r, p0i) = chunk_partial_sums_complex(
             ar, ai, T, C, precision)
         # pin the scan -> outer-reduction boundary (see ``batched_values``;
@@ -568,21 +572,28 @@ def batched_values_complex(Ars, Ais, T: int, C: int, precision: str):
         f = _final_factor(n)
         return P.tf_value(tot_r) * f, P.tf_value(tot_i) * f
 
-    # lax.map, NOT vmap: vmap fuses across the batch axis and XLA's
-    # fusion/contraction choices for the complex product chains vary with
-    # the batch extent (ulp drift between B=1/B=2/B=5 programs, observed
-    # on CPU) -- a scan-over-batch compiles ONE body program whatever B
-    # is, so per-element values cannot depend on the batch or shard shape.
+    def slot(b, vals):
+        vr, vi = one(jax.lax.dynamic_index_in_dim(Ars, b, keepdims=False),
+                     jax.lax.dynamic_index_in_dim(Ais, b, keepdims=False))
+        return (jax.lax.dynamic_update_index_in_dim(vals[0], vr, b, 0),
+                jax.lax.dynamic_update_index_in_dim(vals[1], vi, b, 0))
+
+    # a loop over the batch, NOT vmap: vmap fuses across the batch axis
+    # and XLA's fusion/contraction choices for the complex product chains
+    # vary with the batch extent (ulp drift between B=1/B=2/B=5 programs,
+    # observed on CPU) -- a loop compiles ONE body program whatever B is,
+    # so per-element values cannot depend on the batch or shard shape.
     # Per-matrix SIMD parallelism (the T chunk lanes) is unaffected; what
-    # batching amortizes here is dispatch + compilation, as in PR 1.
-    return jax.lax.map(one, (Ars, Ais))
+    # batching amortizes here is dispatch + compilation.
+    zeros = jnp.zeros(Ars.shape[:1], Ars.dtype)
+    return jax.lax.fori_loop(0, live, slot, (zeros, zeros))
 
 
 @partial(jax.jit, static_argnames=("num_chunks", "precision"))
-def _batched_complex_jit(Ars, Ais, num_chunks: int, precision: str):
+def _batched_complex_jit(Ars, Ais, live, num_chunks: int, precision: str):
     n = Ars.shape[1]
     T, C, _ = chunk_geometry(n, num_chunks)
-    return batched_values_complex(Ars, Ais, T, C, precision)
+    return batched_values_complex(Ars, Ais, live, T, C, precision)
 
 
 def perm_ryser_batched(As, num_chunks: int = 4096, precision: str = "dq_acc"):
@@ -614,33 +625,57 @@ def _complex_batched(As: np.ndarray, num_chunks: int, precision: str):
 
     The planes are split and joined on the host: no complex value ever
     reaches the device, because a TPU has no c128 arithmetic (its x64
-    rewriter aborts the process on c128 ops rather than raising).
+    rewriter aborts the process on c128 ops rather than raising).  The
+    stack runs in the program of the next power-of-two extent, its
+    zero tail as dead slots that cost no Gray steps, so every B shares
+    one of a ladder of programs.
     """
     if As.ndim != 3 or As.shape[1] != As.shape[2]:
         raise ValueError(f"(B, n, n) stack required, got {As.shape}")
-    n = As.shape[1]
+    B, n = As.shape[0], As.shape[1]
     if n == 1:
         return As[:, 0, 0]
     if n == 2:
         return As[:, 0, 0] * As[:, 1, 1] + As[:, 0, 1] * As[:, 1, 0]
+    S = pad_to_rung(As)
     vr, vi = launch_and_wait(_batched_complex_jit,
-                             np.ascontiguousarray(As.real),
-                             np.ascontiguousarray(As.imag),
-                             num_chunks, precision)
-    return vr + 1j * vi
+                             np.ascontiguousarray(S.real),
+                             np.ascontiguousarray(S.imag), np.int32(B),
+                             num_chunks, precision,
+                             attrs={"slots": len(S), "live": B})
+    return (vr + 1j * vi)[:B]
 
 
-def launch_and_wait(program, *args, **kwargs):
+def pow2_slots(B: int) -> int:
+    """The rung: the power-of-two program extent that holds ``B`` matrices.
+
+    Every dense batch path rounds its stack up to it, so arbitrary batch
+    sizes share a ladder of log2(B) programs, and the serve warm-up
+    (``serve.quantized_batches``) compiles exactly that ladder.
+    """
+    return 1 << max(B - 1, 0).bit_length()
+
+
+def pad_to_rung(As: np.ndarray) -> np.ndarray:
+    """The host stack ``As`` with zero matrices appended up to its rung."""
+    pad = pow2_slots(len(As)) - len(As)
+    if not pad:
+        return As
+    return np.concatenate([As, np.zeros((pad,) + As.shape[1:], As.dtype)])
+
+
+def launch_and_wait(program, *args, attrs: dict | None = None, **kwargs):
     """Run one device program and bring its result to the host.
 
     ``program(*args, **kwargs)`` is dispatched under the span
     ``engine.launch`` (argument transfer, a trace on a cache miss, the
-    enqueue); the host's block on its outputs and their copy back run
-    under ``engine.wait``.  Every batch path calls it at its one device
-    sync, so each device program has exactly one ``engine.wait``.
+    enqueue), which carries ``attrs``; the host's block on its outputs
+    and their copy back run under ``engine.wait``.  Every batch path
+    calls it at its one device sync, so each device program has exactly
+    one ``engine.wait``.
     Returns NumPy arrays, a tuple of them for a tuple result.
     """
-    with span("engine.launch"):
+    with span("engine.launch", **(attrs or {})):
         out = program(*args, **kwargs)
     with span("engine.wait"):
         if isinstance(out, tuple):

@@ -67,8 +67,8 @@ def run_permanent_serving(*, n: int = 10, batch: int = 32,
     The result dict gains ``campaign_fraction`` / ``campaign_value``.
 
     Since PR 7 this is a thin wrapper over
-    :class:`repro.serve.PermanentService` in ``fill_first`` mode (bucket
-    quantization off), which reproduces the PR 6 solver-queue flush
+    :class:`repro.serve.PermanentService` in ``fill_first`` mode, which
+    reproduces the PR 6 solver-queue flush
     composition exactly: each bucket reaches ``plan_batch`` with the
     same matrices in the same order, so results are bitwise identical to
     the old direct-queue implementation.  The open-loop continuous-
@@ -177,8 +177,8 @@ def run_permanent_soak(*, n: int = 12, batch: int = 8, requests: int = 64,
     Unlike :func:`run_permanent_serving` (closed-loop, PR 6 flush
     semantics), this drives :class:`repro.serve.PermanentService` in
     continuous mode under Poisson arrivals at ``rate_hz``: partial
-    buckets dispatch whenever the device is free, padded up the
-    power-of-two ladder; lane SLOs shed late work with typed reasons;
+    buckets dispatch whenever the device is free, each in the program
+    of its power-of-two rung; lane SLOs shed late work with typed reasons;
     ``compile_cache``/``warmup`` give a cold process a compile-free
     first bucket.  ``metrics_port`` serves the snapshot as JSON over
     HTTP while the soak runs; ``metrics_json`` writes the final snapshot

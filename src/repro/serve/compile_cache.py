@@ -123,24 +123,20 @@ def enable_compile_cache(path: str | None = None) -> str:
 
 
 def quantized_batches(max_batch: int) -> tuple[int, ...]:
-    """The device-batch sizes the serve loop dispatches: powers of two up
-    to (and including, when itself a power of two) ``max_batch``, capped
-    at the next power of two otherwise.
+    """The device-batch sizes the serve loop dispatches: the rungs
+    (``core.ryser.pow2_slots``) of every bucket size up to ``max_batch``,
+    i.e. the powers of two up to the rung of ``max_batch``.
 
     Quantizing dispatch sizes bounds the trace space -- continuous
     batching produces arbitrary partial buckets, and every distinct
-    (B, n, n) shape is its own trace+compile.  The loop pads a partial
-    bucket up to the next size in this ladder.
+    (B, n, n) shape is its own trace+compile.  The dense batch
+    strategies round a bucket up to its rung below the executor's
+    dedup; warming this ladder compiles every program they can run.
     """
     if max_batch < 1:
         raise ValueError(f"max_batch must be >= 1, got {max_batch}")
-    out = []
-    b = 1
-    while b < max_batch:
-        out.append(b)
-        b *= 2
-    out.append(b)                    # next pow2 >= max_batch
-    return tuple(out)
+    from ..core.ryser import pow2_slots
+    return tuple(1 << k for k in range(pow2_slots(max_batch).bit_length()))
 
 
 def warmup(config, geometries: Sequence[tuple], *,

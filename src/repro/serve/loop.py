@@ -18,17 +18,15 @@ the production concerns the solver queue has no opinion on:
   estimate of queued work exceeds budget.  ``submit`` never raises --
   the returned ticket carries the typed reason and ``result()`` raises
   :class:`~repro.serve.lanes.ShedError`.
-* **Bounded trace space**: dispatched buckets are padded up to a
-  power-of-two ladder (``quantize_buckets``) with *distinct* random
-  filler matrices -- distinct because the executor dedups repeated
-  leaves within a batch and the result cache would swallow repeats
-  across batches, either of which would shrink the device batch back to
-  an unquantized shape.  Combined with the persistent compilation cache
-  and a warm-up pass over the ladder (``serve/compile_cache.py``), a
-  cold process serves its first bucket without a retrace storm.  (With
-  the result cache on, a mid-stream dispatch whose tickets partly hit
-  the cache still runs the device program at the miss count -- the
-  ladder bounds the *cold* trace space, which is where the storm is.)
+* **Bounded trace space**: the service hands the solver only the
+  matrices it serves.  Every dense batch strategy rounds a bucket up to
+  its rung (``core.ryser.pow2_slots``) with zero matrices, below the
+  executor's dedup and result cache, so a partial or partly cached
+  bucket still runs one of a ladder of programs; the complex jnp and
+  mesh engines run those slots dead, at no Gray steps.  Combined with
+  the persistent compilation cache and a warm-up pass over the ladder
+  (``quantize_buckets``, ``serve/compile_cache.py``), a cold process
+  serves its first bucket without a retrace storm.
 * **Observability** (``serve/metrics.py``): every admit/shed/complete/
   dispatch lands in one snapshot schema; ``step`` prints a periodic
   one-line summary.
@@ -37,7 +35,7 @@ the production concerns the solver queue has no opinion on:
   bucket dispatch, and ``drain`` runs the campaign to completion.
 
 ``fill_first=True`` pins the loop to the PR 6 solver-queue semantics
-(dispatch only full or deadline-aged buckets, no shedding, no padding);
+(dispatch only full or deadline-aged buckets, no shedding);
 ``launch/serve.py``'s ``run_permanent_serving`` runs in that mode and is
 bitwise-identical to the old implementation, because a bucket then
 reaches ``plan_batch`` with exactly the same matrices in the same order.
@@ -78,7 +76,7 @@ class ServiceConfig:
     lanes: tuple[LaneSpec, ...] = DEFAULT_LANES
     max_queue_depth: int = 4096          # admission: depth backpressure
     max_pending_cost: float = float("inf")  # admission: step-cost budget
-    quantize_buckets: bool = True        # pad dispatches to the pow2 ladder
+    quantize_buckets: bool = True        # warm up every rung, not max_batch
     fill_first: bool = False             # legacy PR 6 flush semantics
     deadline_s: float = 0.05             # fill_first: bucket age-out trigger
     log_every_s: float = 10.0            # periodic log-line cadence
@@ -114,8 +112,7 @@ class PermanentService:
                  *, distributed_ctx: Any | None = None,
                  campaign: CampaignSpec | None = None,
                  clock: Callable[[], float] | None = None,
-                 log: Callable[[str], None] = print,
-                 filler_seed: int = 0x5eed):
+                 log: Callable[[str], None] = print):
         from ..core.solver import PermanentSolver, SolverConfig
 
         self.scfg = service or ServiceConfig()
@@ -130,9 +127,6 @@ class PermanentService:
         self.metrics = ServeMetrics(self._clock,
                                     lanes=tuple(l.name
                                                 for l in self._queue.lanes))
-        # filler matrices for pow2 padding; its own stream so padding
-        # never perturbs caller-visible randomness
-        self._filler_rng = np.random.default_rng(filler_seed)
         self._ladder = quantized_batches(self.scfg.max_batch)
         # (key, served, plan+execute seconds, trigger) per dispatch --
         # the wrapper in launch/serve.py derives its latency report here;
@@ -339,33 +333,25 @@ class PermanentService:
         return None, None
 
     def _dispatch(self, key: tuple, trigger: str) -> int:
-        n, is_complex = key
+        n, _ = key
         with span("serve.dispatch", n=n, trigger=trigger) as d:
             tickets = self._queue.take(key, self.scfg.max_batch)
             mats = [t.matrix for t in tickets]
-            with span("serve.pad") as pad:
-                if self.scfg.quantize_buckets:
-                    target = next(b for b in self._ladder if b >= len(mats))
-                    for _ in range(target - len(mats)):
-                        F = self._filler_rng.uniform(-1.0, 1.0, (n, n))
-                        if is_complex:
-                            F = F + 1j * self._filler_rng.uniform(
-                                -1.0, 1.0, (n, n))
-                        mats.append(F)
             d.attrs.update(served=len(tickets), lanes=len(mats))
+            t_plan = time.perf_counter()
             plan = self.solver.plan_batch(mats)
             out = self.solver.execute(plan)
             with span("serve.resolve") as resolve:
                 t_done = self._clock()
-                for t, v in zip(tickets, out):  # padded tail discarded
+                for t, v in zip(tickets, out):
                     t._resolve(complex(v) if t.is_complex else float(v),
                                t_done)
                     self.metrics.record_complete(t)
                 self.metrics.record_dispatch(len(tickets),
                                              self.scfg.max_batch)
-            # plan + execute: the time between its neighbours
+            # plan + execute, on the spans' clock
             self.dispatch_log.append((key, len(tickets),
-                                      resolve.t0 - pad.t1, trigger))
+                                      resolve.t0 - t_plan, trigger))
         for t in tickets:
             record("serve.queue", t.t_queued, d.t0, ticket=t.id,
                    dispatch=d.id)

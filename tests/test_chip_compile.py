@@ -62,7 +62,8 @@ def test_dense_batch_compiles(one_chip):
 
 def test_complex_split_plane_batch_compiles(one_chip):
     plane = _sds((4, 20, 20), jnp.float64, one_chip)
-    R._batched_complex_jit.lower(plane, plane, 4096, "dq_acc").compile()
+    live = _sds((), jnp.int32, one_chip)
+    R._batched_complex_jit.lower(plane, plane, live, 4096, "dq_acc").compile()
 
 
 @pytest.mark.parametrize("devices", [1, 4])

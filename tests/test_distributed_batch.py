@@ -66,6 +66,21 @@ def test_batch_on_mesh_tiny_n_closed_forms():
     np.testing.assert_allclose(got, ref, rtol=0)
 
 
+@pytest.mark.parametrize("B,D,extent,live", [
+    (17, 4, 8, [5, 4, 4, 4]),
+    (21, 8, 4, [3, 3, 3, 3, 3, 2, 2, 2]),
+    (32, 4, 8, [8, 8, 8, 8]),
+    (3, 8, 1, [1, 1, 1, 0, 0, 0, 0, 0]),
+    (5, 1, 8, [5]),
+])
+def test_mesh_bucket_is_dealt_evenly_into_rung_shards(B, D, extent, live):
+    """Matrix i goes to shard i % D: the busiest shard holds ceil(B / D)
+    live matrices, in a program whose extent is that count's rung."""
+    got_extent, got_live = distributed._shard_layout(B, D)
+    assert (got_extent, list(got_live)) == (extent, live)
+    assert max(live) == -(-B // D) and sum(live) == B
+
+
 def test_batch_on_mesh_validates_shape():
     with pytest.raises(ValueError):
         distributed.batch_permanents_on_mesh(np.zeros((3, 4, 5)), _mesh1())
@@ -330,6 +345,34 @@ def test_eight_device_complex_dense_bitwise_with_ragged_tail():
                 ref = np.asarray(ryser.perm_ryser_batched(
                     stack, precision=prec))
                 assert np.array_equal(got, ref), (n, B, prec)
+        print("OK")
+    """)
+    assert "OK" in out
+
+
+@pytest.mark.slow
+def test_four_device_ragged_bucket_bitwise_and_balanced():
+    out = _run_sub("""
+        import time
+        from repro.utils import spans
+        mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
+        rng = np.random.default_rng(9)
+        B, n = 17, 8
+        real = rng.uniform(-1, 1, (B, n, n))
+        cplx = rng.normal(size=(B, n, n)) + 1j * rng.normal(size=(B, n, n))
+        for stack in (real, cplx):
+            for prec in ("dd", "dq_fast", "dq_acc", "qq", "kahan"):
+                t0 = time.perf_counter()
+                got = distributed.batch_permanents_on_mesh(
+                    stack, mesh4, precision=prec)
+                launches = [s for s in spans.recent(since=t0)
+                            if s.name == "engine.launch"]
+                ref = np.asarray(ryser.perm_ryser_batched(
+                    stack, precision=prec))
+                assert np.array_equal(got, ref), (stack.dtype, prec)
+                if stack is cplx:   # 4 shards of the rung of 5
+                    (l,) = launches
+                    assert (l.attrs["slots"], l.attrs["live"]) == (32, B)
         print("OK")
     """)
     assert "OK" in out

@@ -6,13 +6,19 @@ batch-grid Pallas kernel, and the bucketed ``permanent_batch`` dispatcher
 sizes, batch-of-one equivalence).
 """
 
+import time
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import engine, oracle, ryser, sparyser
+from repro.core import precision as P
+from repro.core.precision import PRECISION_MODES
 from repro.core.stepspace import Geometry as G
 from repro.kernels import ops
+from repro.utils import spans
 
 RNG = np.random.default_rng(20260725)
 
@@ -80,6 +86,148 @@ def test_ryser_batched_complex_batch_shape_invariant():
 def test_ryser_batched_rejects_non_stack():
     with pytest.raises(ValueError):
         ryser.perm_ryser_batched(jnp.zeros((3, 4, 5)))
+
+
+# ---------------------------------------------------------------------------
+# the complex batch program's dead slots
+# ---------------------------------------------------------------------------
+
+def _lax_map_values_complex(Ars, Ais, T, C, precision):
+    """The complex batch body as a ``lax.map`` over every slot: what the
+    engine ran before it took a live count, kept as the reference."""
+    precision = ryser.complex_precision(precision)
+    n = Ars.shape[1]
+
+    def one(planes):
+        ar, ai = planes
+        parts_r, parts_i, (p0r, p0i) = ryser.chunk_partial_sums_complex(
+            ar, ai, T, C, precision)
+        rh, rl, ih, il, p0r, p0i = jax.lax.optimization_barrier(
+            (parts_r.hi, parts_r.lo, parts_i.hi, parts_i.lo, p0r, p0i))
+        hr, er = ryser.tf_tree_sum(rh, rl)
+        hi_, ei = ryser.tf_tree_sum(ih, il)
+        tot_r = P.tf_add_acc(P.TwoFloat(hr, er), p0r)
+        tot_i = P.tf_add_acc(P.TwoFloat(hi_, ei), p0i)
+        f = ryser._final_factor(n)
+        return P.tf_value(tot_r) * f, P.tf_value(tot_i) * f
+
+    return jax.lax.map(one, (Ars, Ais))
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint64)
+
+
+@pytest.mark.parametrize("precision", PRECISION_MODES)
+@pytest.mark.parametrize("B", [1, 3, 5, 8])
+def test_complex_batch_bit_identical_to_lax_map_body(B, precision):
+    n, chunks = 7, 16
+    rng = np.random.default_rng(B)
+    As = rng.normal(size=(B, n, n)) + 1j * rng.normal(size=(B, n, n))
+    As[0, 1, ::2] = -0.0                   # signed zeros in added columns
+    T, C, _ = ryser.chunk_geometry(n, chunks)
+    ref = jax.jit(_lax_map_values_complex, static_argnums=(2, 3, 4))(
+        As.real, As.imag, T, C, precision)
+    got = ryser.perm_ryser_batched(As, num_chunks=chunks, precision=precision)
+    assert got.shape == (B,)
+    np.testing.assert_array_equal(_bits(got.real), _bits(ref[0]))
+    np.testing.assert_array_equal(_bits(got.imag), _bits(ref[1]))
+
+
+@pytest.mark.parametrize("live", [1, 5, 17, 32])
+def test_dead_slots_read_zero_and_leave_live_values(live):
+    rng = np.random.default_rng(live)
+    Ar, Ai = rng.normal(size=(2, 32, 5, 5))
+    full = ryser._batched_complex_jit(Ar, Ai, np.int32(32), 16, "dq_acc")
+    part = ryser._batched_complex_jit(Ar, Ai, np.int32(live), 16, "dq_acc")
+    for p, f in zip(part, full):
+        np.testing.assert_array_equal(_bits(p[:live]), _bits(f[:live]))
+        assert not np.any(_bits(p[live:]))     # +0.0, not a signed zero
+
+
+def _gray_scans(jaxpr, lane_shape):
+    """Scans whose carry holds the (n, T) row-sum state, at any depth."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            body = eqn.params["jaxpr"].jaxpr
+            k0 = eqn.params["num_consts"]
+            carry = body.invars[k0:k0 + eqn.params["num_carry"]]
+            if any(tuple(v.aval.shape) == lane_shape for v in carry):
+                found.append(eqn)
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                found += _gray_scans(sub, lane_shape)
+    return found
+
+
+def test_gray_scan_runs_under_the_live_bound():
+    n, B = 6, 4
+    T, C, _ = ryser.chunk_geometry(n, 4)
+    planes = jnp.zeros((B, n, n))
+    closed = jax.make_jaxpr(
+        lambda r, i, live: ryser.batched_values_complex(r, i, live, T, C,
+                                                        "dq_acc"))(
+        planes, planes, jnp.int32(B))
+    live = closed.jaxpr.invars[2]
+    assert len(_gray_scans(closed.jaxpr, (n, T))) == 1
+    (loop,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "while"]
+    assert _gray_scans(loop.params["body_jaxpr"].jaxpr, (n, T))
+    # the loop's bound is the live count: it enters the carry the
+    # loop's condition compares the slot index with
+    assert live in loop.invars
+    k = loop.params["cond_nconsts"] + loop.params["body_nconsts"]
+    bound = loop.params["cond_jaxpr"].jaxpr.invars[
+        loop.params["cond_nconsts"] + loop.invars[k:].index(live)]
+    (lt,) = loop.params["cond_jaxpr"].jaxpr.eqns
+    assert lt.primitive.name == "lt" and lt.invars[1] is bound
+
+
+def test_one_compile_for_every_live_count():
+    rng = np.random.default_rng(7)
+    Ar, Ai = rng.normal(size=(2, 8, 5, 5))
+    ryser._batched_complex_jit(Ar, Ai, np.int32(3), 16, "dq_acc")
+    size = ryser._batched_complex_jit._cache_size()
+    ryser._batched_complex_jit(Ar, Ai, np.int32(6), 16, "dq_acc")
+    assert ryser._batched_complex_jit._cache_size() == size
+
+
+def test_odd_stack_runs_in_its_rung_program():
+    rng = np.random.default_rng(11)
+    As = rng.normal(size=(8, 5, 5)) + 1j * rng.normal(size=(8, 5, 5))
+    ryser.perm_ryser_batched(As, num_chunks=16)
+    size = ryser._batched_complex_jit._cache_size()
+    t0 = time.perf_counter()
+    got = ryser.perm_ryser_batched(As[:5], num_chunks=16)
+    assert ryser._batched_complex_jit._cache_size() == size
+    (launch,) = [s for s in spans.recent(since=t0)
+                 if s.name == "engine.launch"]
+    assert (launch.attrs["slots"], launch.attrs["live"]) == (8, 5)
+    ref = np.array([oracle.perm_ryser_exact(A) for A in As[:5]])
+    np.testing.assert_allclose(got, ref, rtol=1e-10)
+
+
+@pytest.mark.parametrize("backend,program,dtype", [
+    ("jnp", ryser._batched_jit, float),
+    ("pallas", ops._pallas_values_jit, float),
+    ("pallas", ops._pallas_values_jit, complex),
+])
+def test_dense_bucket_runs_in_its_rung_program(backend, program, dtype):
+    """A bucket of 3 runs in the program of 4: no compile of its own, and
+    its values are those of the same matrices in a full bucket."""
+    from repro.core.executor import get_backend
+    rng = np.random.default_rng(5)
+    As = rng.normal(size=(4, 5, 5))
+    if dtype is complex:
+        As = As + 1j * rng.normal(size=(4, 5, 5))
+    run = get_backend(backend).dense_batch
+    full = run(As, precision="dq_acc", num_chunks=16)
+    size = program._cache_size()
+    got = run(As[:3], precision="dq_acc", num_chunks=16)
+    assert program._cache_size() == size
+    assert got.shape == (3,)
+    np.testing.assert_array_equal(got, full[:3])
 
 
 # ---------------------------------------------------------------------------

@@ -134,9 +134,9 @@ def _dense_body(kind, n, T, C, precision):
     Ar, Ai = jnp.asarray(planes[0]), jnp.asarray(planes[1])
     first = jnp.asarray(0, dtype=jnp.int32)
     if kind == "complex_batch":
-        return (lambda r, i: ryser.batched_values_complex(r, i, T, C,
-                                                          precision),
-                (Ar, Ai))
+        return (lambda r, i, live: ryser.batched_values_complex(
+                    r, i, live, T, C, precision),
+                (Ar, Ai, jnp.asarray(2, dtype=jnp.int32)))
     if kind == "real_chunked":
         return (lambda a: ryser.chunk_partial_sums(a, T, C, precision),
                 (Ar[0],))

@@ -103,6 +103,54 @@ class TestLanes:
         for t, M in zip(ts, mats):
             assert t.result() == permanent(M)
 
+    def test_complex_bucket_gets_no_filler(self, monkeypatch):
+        """A complex bucket reaches the solver as its served matrices;
+        the engine rounds it up to the ladder's rung with dead slots."""
+        clock = FakeClock()
+        svc = service(clock, max_batch=8)
+        handed = []
+        plan_batch = svc.solver.plan_batch
+        monkeypatch.setattr(svc.solver, "plan_batch",
+                            lambda mats: handed.append(len(mats))
+                            or plan_batch(mats))
+        rng = np.random.default_rng(3)
+        mats = [mk(rng, n=5, complex_entries=True) for _ in range(3)]
+        t0 = time.perf_counter()
+        ts = [svc.submit(M, deadline_s=None) for M in mats]
+        svc.drain()
+        got = spans.recent(since=t0)
+        assert handed == [3]
+        (d,) = [s for s in got if s.name == "serve.dispatch"]
+        assert d.attrs["served"] == d.attrs["lanes"] == 3
+        (launch,) = [s for s in got if s.name == "engine.launch"]
+        assert (launch.attrs["slots"], launch.attrs["live"]) == (4, 3)
+        for t, M in zip(ts, mats):
+            assert t.result() == permanent(M)
+
+
+    def test_real_bucket_rounds_below_the_service(self, monkeypatch):
+        """A real bucket of 3 reaches the solver as its 3 matrices and
+        runs in the program its rung of 4 compiled: no compile of its
+        own, no filler."""
+        from repro.core import ryser
+        clock = FakeClock()
+        svc = service(clock, max_batch=8)
+        handed = []
+        plan_batch = svc.solver.plan_batch
+        monkeypatch.setattr(svc.solver, "plan_batch",
+                            lambda mats: handed.append(len(mats))
+                            or plan_batch(mats))
+        rng = np.random.default_rng(4)
+        for k in (4, 3):
+            mats = [mk(rng, n=5) for _ in range(k)]
+            ts = [svc.submit(M, deadline_s=None) for M in mats]
+            svc.drain()
+            if k == 4:
+                size = ryser._batched_jit._cache_size()
+            for t, M in zip(ts, mats):
+                assert t.result() == permanent(M)
+        assert handed == [4, 3]
+        assert ryser._batched_jit._cache_size() == size
 
 # -- deadlines / shedding -----------------------------------------------------
 
@@ -278,9 +326,11 @@ class TestMetrics:
         assert t["count"] >= 1 and t["total_s"] > 0
 
     def test_dispatch_spans_match_the_log(self):
-        """A drained service's serve.dispatch spans carry the served and
-        padded lane counts of its dispatch_log and ladder; each ticket
-        has one serve.queue span naming the dispatch that took it."""
+        """A drained service's serve.dispatch spans carry the served
+        counts of its dispatch_log, and as lanes the matrices handed to
+        the solver, which are the served ones (the batch engine rounds
+        to the rung below); each ticket has one serve.queue span naming
+        the dispatch that took it."""
         clock = FakeClock()
         svc = service(clock, max_batch=8)
         rng = np.random.default_rng(21)
@@ -290,10 +340,9 @@ class TestMetrics:
         got = spans.recent(since=t0)
         disp = [s for s in got if s.name == "serve.dispatch"]
         assert [(d.attrs["served"], d.attrs["lanes"]) for d in disp] == \
-            [(8, 8), (3, 4)]
+            [(8, 8), (3, 3)]
         assert [d.attrs["served"] for d in disp] == \
             [served for _, served, _, _ in svc.dispatch_log]
-        assert all(d.attrs["lanes"] in quantized_batches(8) for d in disp)
         assert [(d.attrs["n"], d.attrs["trigger"]) for d in disp] == \
             [(5, "ready")] * 2
         queue = {s.attrs["ticket"]: s for s in got if s.name == "serve.queue"}
@@ -305,14 +354,16 @@ class TestMetrics:
             assert q.t0 == t.t_queued and q.t1 == d.t0
         for d, (_, _, seconds, _) in zip(disp, svc.dispatch_log):
             kids = {s.name: s for s in got if s.parent == d.id}
-            assert set(kids) == {"serve.pad", "solver.plan",
-                                 "solver.execute", "serve.resolve"}
+            assert set(kids) == {"solver.plan", "solver.execute",
+                                 "serve.resolve"}
             plan, execute = kids["solver.plan"], kids["solver.execute"]
             assert plan.seconds + execute.seconds <= seconds
-            assert seconds == kids["serve.resolve"].t0 - kids["serve.pad"].t1
+            # the log times plan + execute: from before the plan span
+            # to the start of the resolve span
+            assert d.t0 <= kids["serve.resolve"].t0 - seconds <= plan.t0
         timings = svc.solver.stats()["leaf_timings"]
         assert timings["dense_batch(n=5,jnp)"]["count"] == 2
-        assert timings["dense_batch(n=5,jnp)"]["leaves"] == 12
+        assert timings["dense_batch(n=5,jnp)"]["leaves"] == 11
 
     def test_metrics_http_endpoint(self):
         clock = FakeClock()

@@ -233,7 +233,8 @@ def test_readers_of_the_service():
         svc.submit(rng.uniform(-1, 1, (5, 5)), deadline_s=None)
     svc.drain()
     run = _run(t0, time.perf_counter() - t0)
-    assert _reader("serve.filler_share")(run) == pytest.approx(100 / 12)
+    # the service hands the solver only what it serves: 8, then 3
+    assert _reader("serve.filler_share")(run) == 0.0
     assert _reader("serve.queue_ms")(run) > 0
     assert _reader("device_wait_ms.serve")(run) > 0
     got = spans.recent(since=t0)
